@@ -297,6 +297,8 @@ def main():
     import argparse
 
     from benchmarks.common import emit
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--mix", choices=["full", "standard2"], default=None,
                     help="also MEASURE this TPC-C mix through the engine")

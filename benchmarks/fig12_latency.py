@@ -125,6 +125,8 @@ def main():
     import sys
 
     from benchmarks.common import emit
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()

@@ -1,19 +1,18 @@
 """Figure 13 (repro): cluster scalability with node count + live recovery.
 
 Weak scaling in the TPC-C/YCSB tradition — every node brings its own
-partitions and its own offered load — over *forced host devices* (the
-device-count trick: each subprocess restarts jax with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``, N ∈ {1, 2, 4, 8};
-one device == one paper node).  Each worker runs the REAL distributed
-runtime (`repro.cluster.ClusterRuntime`: shard_map partitioned phase with
+partitions and its own offered load — over sub-meshes of one process's
+devices (``jax.devices()[:N]``, N ∈ {1, 2, 4, 8}; on a CPU host ``main``
+forces 8 host devices; one device == one paper node).  Each size runs the
+REAL distributed runtime (`repro.cluster.ClusterRuntime`: shard_map partitioned phase with
 zero collectives, slab-streamed op-stream shipping to the full replica
 DURING execution, psum fence waiting only on the unshipped tail slab,
 single-master phase on the full replica's device) and reports measured
 partitioned-phase throughput plus the §5 stream-byte split — bytes
-overlapped with execution vs bytes exposed at the fence; the parent
+overlapped with execution vs bytes exposed at the fence; the sweep
 asserts the cluster metric grows monotonically from N=1 to N=8 AND that
 the fence-exposed bytes under streaming are strictly lower than the
-ship-everything-at-the-fence baseline (``--slabs 1``, the pre-streaming
+ship-everything-at-the-fence baseline (``slabs=1``, the pre-streaming
 behavior) on the N=4 configuration.
 
 Measurement contract (small host, simulated nodes): the N simulated
@@ -39,7 +38,7 @@ full replica (real donor copy — the block is scribbled first),
 re-executes, and the run reports the measured recovery latency with
 ``replica_consistent()`` holding at the next fence.
 
-``--mix full`` runs the five-transaction TPC-C mix (ordered indexes,
+``mix="full"`` runs the five-transaction TPC-C mix (ordered indexes,
 Delivery/OrderStatus/StockLevel scans) through the cluster runtime
 instead of YCSB — the CI full-mix smoke drives a 4-node kill-one-node
 pass this way with a regression floor on the overlapped-bytes fraction.
@@ -50,16 +49,20 @@ pass this way with a regression floor on the overlapped-bytes fraction.
 import argparse
 import json
 import os
-import subprocess
-import sys
 
 NODE_COUNTS = (1, 2, 4, 8)
+_FORCE_DEVICES = "--xla_force_host_platform_device_count"
 
 
 # ---------------------------------------------------------------------------
-# worker: one process == one cluster size (jax restarts with N devices)
+# one cluster size over the first N devices of this process
 # ---------------------------------------------------------------------------
-def worker(args):
+def measure(N: int, *, ppn: int = 2, rows: int = 256,
+            txns_per_node: int = 96, epochs: int = 6,
+            kill: tuple[int, int] | None = None, slabs: int = 4,
+            mix: str = "ycsb") -> dict:
+    """Run the cluster runtime on a sub-mesh of N devices.  ``kill`` is
+    (node, epoch) for a FaultInjector kill.  Returns the run's telemetry."""
     import jax
     import numpy as np
 
@@ -67,28 +70,30 @@ def worker(args):
     from repro.core.fault import FaultInjector
     from repro.obs import MetricsRegistry
 
-    N = jax.device_count()
-    P = N * args.ppn
-    mesh = jax.make_mesh((N,), ("part",))
+    if jax.device_count() < N:
+        raise RuntimeError(
+            f"fig13 needs {N} devices, found {jax.device_count()}: on a CPU "
+            f"host set XLA_FLAGS={_FORCE_DEVICES}={max(NODE_COUNTS)}")
+    P = N * ppn
+    mesh = jax.make_mesh((N,), ("part",), devices=jax.devices()[:N])
     inj = None
-    if args.kill:
-        node, ep = (int(x) for x in args.kill.split(":"))
+    if kill:
         inj = FaultInjector()
-        inj.schedule_kill(node, ep)
+        inj.schedule_kill(*kill)
 
     def pad(a, axis, target):
         w = [(0, 0)] * a.ndim
         w[axis] = (0, target - a.shape[axis])
         return np.pad(a, w)
 
-    txns = args.txns_per_node * N                 # weak scaling
+    txns = txns_per_node * N                      # weak scaling
     # fixed device shapes across epochs (the service batcher's invariant):
     # per-epoch draws vary T/B slightly, and letting the pow2 pad wobble
     # would recompile the mesh programs mid-measurement
-    T_fix = 1 << (args.txns_per_node // args.ppn + 8).bit_length()
+    T_fix = 1 << (txns_per_node // ppn + 8).bit_length()
     B_fix = 1 << max(16, int(txns * 0.3)).bit_length()
 
-    if args.mix == "full":
+    if mix == "full":
         from repro.db import tpcc
         cfg = tpcc.TPCCConfig(n_partitions=P, n_items=400,
                               cust_per_district=40, order_ring=64,
@@ -97,7 +102,7 @@ def worker(args):
         init = tpcc.init_values(cfg, np.random.default_rng(7), state=state)
         rt = ClusterRuntime(mesh, P, cfg.rows_per_partition, init_val=init,
                             indexes=tpcc.index_specs(cfg), injector=inj,
-                            n_slabs=args.slabs)
+                            n_slabs=slabs)
 
         def make(seed):
             b = tpcc.make_batch(cfg, state, txns, seed=seed)
@@ -112,10 +117,8 @@ def worker(args):
             return b
     else:
         from repro.db import ycsb
-        cfg = ycsb.YCSBConfig(n_partitions=P,
-                              records_per_partition=args.rows)
-        rt = ClusterRuntime(mesh, P, args.rows, injector=inj,
-                            n_slabs=args.slabs)
+        cfg = ycsb.YCSBConfig(n_partitions=P, records_per_partition=rows)
+        rt = ClusterRuntime(mesh, P, rows, injector=inj, n_slabs=slabs)
 
         def make(seed):
             b = ycsb.make_batch(cfg, txns, seed=seed)
@@ -125,8 +128,8 @@ def worker(args):
 
     rt.run_epoch(make(999))                       # jit warm
     recoveries = []
-    # per-epoch registry snapshots ride the RESULT JSON back to the sweep
-    # parent: engine stats + the per-node fence-wait/committed arrays +
+    # per-epoch registry snapshots ride the result back to the sweep:
+    # engine stats + the per-node fence-wait/committed arrays +
     # the recovery ledger, under the same namespaces the service exports
     reg = MetricsRegistry()
     reg.register_object("engine", rt.stats)
@@ -144,7 +147,7 @@ def worker(args):
     reg.register_provider("cluster", _node_metrics)
     consistent_after_recovery = True
     t_parts, commits = [], []
-    for ep in range(args.epochs):
+    for ep in range(epochs):
         c0, p0 = rt.stats.committed_single, rt.stats.part_time_s
         m = rt.run_epoch(make(ep))
         t_parts.append(rt.stats.part_time_s - p0)
@@ -171,7 +174,7 @@ def worker(args):
     node_c = rt.eng.node_committed.astype(int)
     s = rt.stats
     stream_total = int(s.op_bytes_overlapped + s.op_bytes_fence)
-    print("RESULT " + json.dumps({
+    return {
         "n_nodes": N,
         "committed_single": int(sum(commits)),
         "part_s": round(sum(t_parts), 4),
@@ -195,34 +198,7 @@ def worker(args):
         "consistent": bool(rt.replica_consistent()
                            and consistent_after_recovery),
         "metrics": reg.snapshots,
-    }))
-
-
-def _spawn(n_devices: int, extra: list[str]) -> dict:
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}")
-    cmd = [sys.executable, "-m", "benchmarks.fig13_scalability", "--worker",
-           *extra]
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                         timeout=480)
-    # a child that dies (OOM, assert, import error) must fail the sweep
-    # LOUDLY — a silent hole in the curve reads as a missing data point
-    if out.returncode != 0:
-        sys.stderr.write(f"fig13 worker FAILED (N={n_devices}, "
-                         f"exit {out.returncode}): {' '.join(cmd)}\n")
-        sys.stderr.write("---- child stderr ----\n")
-        sys.stderr.write(out.stderr[-8000:] + "\n")
-        raise RuntimeError(
-            f"fig13 worker exited {out.returncode} at N={n_devices}")
-    lines = [ln for ln in out.stdout.splitlines()
-             if ln.startswith("RESULT ")]
-    if not lines:
-        sys.stderr.write("---- child stdout ----\n" + out.stdout[-4000:]
-                         + "\n---- child stderr ----\n"
-                         + out.stderr[-4000:] + "\n")
-        raise RuntimeError(
-            f"fig13 worker (N={n_devices}) produced no RESULT line")
-    return json.loads(lines[-1][len("RESULT "):])
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -233,20 +209,20 @@ def run():
 
 def sweep(smoke: bool = False, sweep_json: str | None = None):
     if smoke:
-        scale = ["--rows", "64", "--txns-per-node", "48", "--epochs", "10"]
+        scale = {"rows": 64, "txns_per_node": 48, "epochs": 10}
         repeats = 2
     else:
-        scale = ["--rows", "256", "--txns-per-node", "64", "--epochs", "16"]
+        scale = {"rows": 256, "txns_per_node": 64, "epochs": 16}
         repeats = 3
     rows, thr = [], {}
     results = {}
     for n in NODE_COUNTS:
-        # best-of-k fresh processes: run-to-run variance on a small shared
-        # host (scheduler state, pool warm-up) dwarfs in-run noise; the
-        # best run is the least-interfered estimate of the machine
+        # best-of-k runs: run-to-run variance on a small shared host
+        # (scheduler state, pool warm-up) dwarfs in-run noise; the best
+        # run is the least-interfered estimate of the machine
         best = None
         for _ in range(repeats):
-            cand = _spawn(n, scale)
+            cand = measure(n, **scale)
             assert cand["consistent"], f"replicas diverged at N={n}"
             if best is None or cand["part_txn_s"] > best["part_txn_s"]:
                 best = cand
@@ -270,9 +246,9 @@ def sweep(smoke: bool = False, sweep_json: str | None = None):
                  round(thr[8] / max(thr[1], 1), 2)))
 
     # ---- N=4: in-phase streaming vs the fence-time-replay baseline -----
-    # --slabs 1 ships the whole epoch stream at the fence (the PR-4
+    # slabs=1 ships the whole epoch stream at the fence (the PR-4
     # behavior); streamed fence-exposed bytes must be strictly lower
-    base = _spawn(4, scale + ["--slabs", "1"])
+    base = measure(4, **scale, slabs=1)
     streamed = results[4]
     assert base["consistent"], "baseline replicas diverged"
     assert base["op_bytes_fence"] > 0, base
@@ -286,7 +262,7 @@ def sweep(smoke: bool = False, sweep_json: str | None = None):
                  streamed["op_bytes_overlapped"]))
 
     # ---- kill one node mid-run at N=8: classified recovery, consistent --
-    r = _spawn(8, scale + ["--kill", "3:3"])
+    r = measure(8, **scale, kill=(3, 3))
     assert r["consistent"], "replicas diverged after recovery"
     assert len(r["recoveries"]) == 1, r["recoveries"]
     ev = r["recoveries"][0]
@@ -299,7 +275,7 @@ def sweep(smoke: bool = False, sweep_json: str | None = None):
     rows.append(("fig13/recovery_run_throughput_txn_s", 0.0,
                  r["part_txn_s"]))
     if sweep_json:
-        # persist every child's full telemetry — per-epoch registry
+        # persist every run's full telemetry — per-epoch registry
         # snapshots (engine.* + cluster.node*.* + recovery ledger)
         # included — so perf trajectories survive the sweep
         with open(sweep_json, "w") as f:
@@ -317,9 +293,8 @@ def full_mix_smoke(sweep_json: str | None = None):
     cluster with a mid-run node kill — recovery classified, replicas
     (records + index segments) consistent, and a floor on the
     overlapped-bytes fraction (> 0: the op stream really ships in-phase)."""
-    scale = ["--mix", "full", "--txns-per-node", "40", "--epochs", "8",
-             "--ppn", "1", "--kill", "1:3"]
-    r = _spawn(4, scale)
+    r = measure(4, mix="full", txns_per_node=40, epochs=8, ppn=1,
+                kill=(1, 3))
     assert r["consistent"], "full-mix replicas diverged"
     assert len(r["recoveries"]) == 1, r["recoveries"]
     ev = r["recoveries"][0]
@@ -342,8 +317,20 @@ def full_mix_smoke(sweep_json: str | None = None):
     return rows, r, ev
 
 
+def force_host_devices():
+    """Every cluster size is a sub-mesh of this one process's devices; on a
+    CPU host that means 8 forced host devices.  Call before JAX starts."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if _FORCE_DEVICES not in flags:
+        os.environ["XLA_FLAGS"] = \
+            f"{flags} {_FORCE_DEVICES}={max(NODE_COUNTS)}".strip()
+
+
 def main():
+    force_host_devices()
     from benchmarks.common import emit
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny scale; asserts the monotonic-scaling and "
@@ -351,25 +338,12 @@ def main():
     ap.add_argument("--full-smoke", action="store_true", dest="full_smoke",
                     help="4-node full-TPC-C-mix smoke: kill-one-node "
                     "recovery + overlapped-bytes floor (CI gate)")
-    ap.add_argument("--worker", action="store_true", help=argparse.SUPPRESS)
-    ap.add_argument("--ppn", type=int, default=2, help=argparse.SUPPRESS)
-    ap.add_argument("--rows", type=int, default=256, help=argparse.SUPPRESS)
-    ap.add_argument("--txns-per-node", type=int, default=96,
-                    dest="txns_per_node", help=argparse.SUPPRESS)
-    ap.add_argument("--epochs", type=int, default=6, help=argparse.SUPPRESS)
-    ap.add_argument("--kill", default=None, help=argparse.SUPPRESS)
-    ap.add_argument("--slabs", type=int, default=4, help=argparse.SUPPRESS)
-    ap.add_argument("--mix", default="ycsb", choices=("ycsb", "full"),
-                    help=argparse.SUPPRESS)
     ap.add_argument("--sweep-json", metavar="PATH", default=None,
                     dest="sweep_json",
-                    help="persist every child's RESULT JSON — per-epoch "
+                    help="persist every run's telemetry — per-epoch "
                     "registry snapshots (engine.* / cluster.node*.*) and "
                     "recovery stats included — to this file")
     args = ap.parse_args()
-    if args.worker:
-        worker(args)
-        return
     if args.full_smoke:
         rows, r, ev = full_mix_smoke(sweep_json=args.sweep_json)
         print("name,us_per_call,derived")

@@ -264,6 +264,8 @@ def main():
     import json
 
     from benchmarks.common import emit
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes + parity + traffic-claim gate (CI)")

@@ -10,6 +10,7 @@ from benchmarks import (fig03_model, fig10_improvement, fig11_throughput,
                         fig14_overhead, fig15_replication, fig16_scalability,
                         roofline_report)
 from benchmarks.common import emit
+from repro import compile_cache
 
 ALL = {
     "fig03": fig03_model, "fig10": fig10_improvement,
@@ -21,6 +22,8 @@ ALL = {
 
 
 def main() -> None:
+    fig13_scalability.force_host_devices()
+    compile_cache.enable()
     which = sys.argv[1:] or list(ALL)
     print("name,us_per_call,derived")
     for name in which:

@@ -9,11 +9,14 @@
 """
 import jax
 
+from repro import compile_cache
 from repro.configs import get_arch
 from repro.core.engine import StarEngine
 from repro.db import ycsb
 from repro.launch.mesh import make_host_mesh
 from repro.train.trainer import Trainer, TrainerConfig
+
+compile_cache.enable()
 
 # --- 1. STAR transaction engine ------------------------------------------
 print("== STAR engine (YCSB, 4 partitions) ==")

@@ -52,6 +52,7 @@ import numpy as np
 
 import jax
 
+from repro import compile_cache
 from repro.cluster import ClusterRuntime, ClusterTxnService
 from repro.core.fault import FaultInjector
 from repro.db import tpcc, ycsb
@@ -90,6 +91,7 @@ if ANALYTICS and MIX != "full":
 
 
 def main():
+    compile_cache.enable()
     tracer = None
     if TRACE:
         tracer = Tracer(capacity=1 << 18, enabled=True)

@@ -7,9 +7,12 @@ Thomas-rule tid check (stale loads are rejected).
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import get_arch
 from repro.models import transformer as tf
 from repro.serve.engine import ServeEngine
+
+compile_cache.enable()
 
 cfg = get_arch("starcoder2-7b", smoke=True)
 params_v1 = tf.init_params(cfg, jax.random.key(0))

@@ -14,11 +14,13 @@ import sys
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.engine import StarEngine
 from repro.db import ycsb
 from repro.service import (AdmissionConfig, OpenLoopClient, TxnService,
                            YCSBSource)
 
+compile_cache.enable()
 QUICK = "--quick" in sys.argv
 DUR = 0.5 if QUICK else 2.0
 

@@ -5,10 +5,12 @@ savings, epoch fences, failure + recovery across all four §4.5.3 cases.
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core.engine import StarEngine
 from repro.core.fault import ClusterConfig, classify_failure
 from repro.db import tpcc
 
+compile_cache.enable()
 cfg = tpcc.TPCCConfig(n_partitions=4, n_items=2000, cust_per_district=200,
                       order_ring=128)
 state = tpcc.TPCCState(cfg)

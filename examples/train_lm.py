@@ -8,6 +8,7 @@ Quick verification:
 """
 import argparse
 
+from repro import compile_cache
 from repro.configs.base import ArchConfig, BLOCK_ATTN_MLP
 from repro.launch.mesh import make_host_mesh
 from repro.train.optimizer import AdamWConfig
@@ -24,6 +25,7 @@ LM110M = ArchConfig(
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--seq", type=int, default=256)

@@ -53,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.replication import thomas_apply
-from repro.db.tpcc import N_DIST
+from repro.db import tpcc
 
 #: value columns the views project: col 0 (next_o_id / s_qty / o_id ...),
 #: col 2 (order-line amount / d_ytd ...) and col 5 (order latency in
@@ -196,24 +196,24 @@ class MaterializedViews:
         flag, and the fence stamp is the only consumer (once per epoch)."""
         cfg = self.cfg
         P = proj.shape[0]
-        ring = cfg.order_ring
+        ring, nd = cfg.order_ring, tpcc.N_DIST
         ol = proj[:, cfg.off_order_line:
-                  cfg.off_order_line + N_DIST * ring * 15, 1]
+                  cfg.off_order_line + nd * ring * 15, 1]
         st = proj[:, cfg.off_stock:cfg.off_stock + cfg.n_items, 0]
-        no = proj[:, cfg.off_new_order:cfg.off_new_order + N_DIST * ring, 0]
+        no = proj[:, cfg.off_new_order:cfg.off_new_order + nd * ring, 0]
         # Delivery stamps the order's age (in order-ids, always >= 1) in
         # orders col 5; NewOrder's whole-row SET zeroes it on ring reuse,
         # so lat > 0 selects exactly the ring's delivered-and-retained
         # orders.  Counts are exact integers — bit-equal to the oracle.
-        lat = proj[:, cfg.off_orders:cfg.off_orders + N_DIST * ring,
-                   2].reshape(P, N_DIST, ring)
+        lat = proj[:, cfg.off_orders:cfg.off_orders + nd * ring,
+                   2].reshape(P, nd, ring)
         live = lat > 0
         return {
             "revenue": ol.astype(np.int64).reshape(
-                P, N_DIST, ring * 15).sum(axis=-1),
+                P, nd, ring * 15).sum(axis=-1),
             "stock_low": (st < self.stock_threshold).sum(
                 axis=-1).astype(np.int32),
-            "undelivered": (no.reshape(P, N_DIST, ring) != 0).sum(
+            "undelivered": (no.reshape(P, nd, ring) != 0).sum(
                 axis=-1).astype(np.int32),
             "order_latency": np.stack(
                 [(live & (lat <= b)).sum(axis=-1)

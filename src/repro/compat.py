@@ -1,22 +1,13 @@
-"""Version compatibility shims for the pinned container's jax.
+"""``shard_map`` as the engines call it.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (jax 0.4.x, flag
-``check_rep``) to ``jax.shard_map`` (newer jax, flag ``check_vma``).  Code
-under ``src/`` calls this module's :func:`shard_map` so both jax versions
-drive the same mesh programs; replication checking is disabled on both
-paths (the engines manage replication explicitly).
+Code under ``src/`` calls this module's :func:`shard_map`; replication
+checking is disabled (the engines manage replication explicitly).
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -45,8 +45,9 @@ Its ``run_epoch`` returns the same metric surface as
 ``StarEngine.run_epoch``, so ``service.TxnService`` drives either engine
 unchanged.
 
-On this host the mesh axes are 1-8 forced CPU devices (tests); the same
-code paths lower for a TPU slice.
+Tests run the mesh on 1-8 forced CPU devices; ``chip_smoke.py --chips 4``
+runs it on four TPU chips, and ``tests/test_tpu_compile.py`` compiles its
+programs (:class:`MeshPrograms`) for a described v5e:2x2.
 """
 from __future__ import annotations
 
@@ -56,14 +57,14 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.baselines.cost_model import Network
 from repro.changelog.log import ChangeLog
 from repro.compat import shard_map
 from repro.core import replication as repl
-from repro.core.engine import EngineStats
+from repro.core.engine import EngineStats, check_kernel
 from repro.core.partitioned import run_partitioned
 from repro.core.phase_switch import PhaseController
 from repro.core.single_master import run_single_master
@@ -103,14 +104,14 @@ class _ReplicaShip:
         with obs.span("replica.replay_full", cat="replay",
                       epoch=info["epoch"], slab=info["slab"]):
             log_m = jax.device_put(log, eng._master_dev)
-            eng.full_val, eng.full_tid, fidx = eng._replay_full(
+            eng.full_val, eng.full_tid, fidx = eng.prog.replay_full(
                 eng.full_val, eng.full_tid, log_m, eng.full_idx)
             if eng.has_index:
                 eng.full_idx = fidx
         if eng.secondary:
             with obs.span("replica.replay_secondary", cat="replay",
                           epoch=info["epoch"], slab=info["slab"]):
-                eng.sec_val, eng.sec_tid, sidx = eng._replay_sec(
+                eng.sec_val, eng.sec_tid, sidx = eng.prog.replay_sec(
                     eng.sec_val, eng.sec_tid, log, eng.sec_idx)
                 if eng.has_index:
                     eng.sec_idx = sidx
@@ -124,21 +125,21 @@ class _ReplicaShip:
                 jnp.where(w, slog["row"].reshape(-1), -1), eng._bcast)
             vals = jax.device_put(slog["val"].reshape(-1, eng.C), eng._bcast)
             tids = jax.device_put(slog["tid"].reshape(-1), eng._bcast)
-            eng.part_val, eng.part_tid = eng._scatter(
+            eng.part_val, eng.part_tid = eng.prog.scatter(
                 eng.part_val, eng.part_tid, rows, vals, tids)
             if eng.secondary:
-                eng.sec_val, eng.sec_tid = eng._scatter_sec(
+                eng.sec_val, eng.sec_tid = eng.prog.scatter_sec(
                     eng.sec_val, eng.sec_tid, rows, vals, tids)
             if eng.has_index:
                 kb = jax.device_put(stream["kinds"], eng._bcast)
                 db = jax.device_put(stream["delta"], eng._bcast)
                 iwb = jax.device_put(slog["iwrite"], eng._bcast)
                 tdb = jax.device_put(slog["tid"], eng._bcast)
-                eng.part_idx = eng._sm_idx_replay(eng.part_idx, kb, db,
-                                                  iwb, tdb)
+                eng.part_idx = eng.prog.sm_idx_replay(
+                    eng.part_idx, kb, db, iwb, tdb)
                 if eng.secondary:
-                    eng.sec_idx = eng._sm_idx_replay_sec(eng.sec_idx, kb, db,
-                                                         iwb, tdb)
+                    eng.sec_idx = eng.prog.sm_idx_replay_sec(
+                        eng.sec_idx, kb, db, iwb, tdb)
 
 
 class ClusterStarEngine:
@@ -154,9 +155,9 @@ class ClusterStarEngine:
         row p holds partition (p - ppn) mod P, i.e. node m hosts node
         m-1's block (ClusterConfig.partition_homes round-robin).  Every
         site that materializes, resyncs, reloads, or checks the
-        secondary copies goes through this shift."""
-        return jax.tree.map(lambda a: jnp.roll(a, self.ppn, axis=0),
-                            tree)
+        secondary copies goes through this shift — a collective permute
+        of whole node blocks over the ``part`` axis."""
+        return self.prog.ship_home(jax.device_put(tree, self._shard))
 
     def __init__(self, mesh, n_partitions: int, rows_per_partition: int,
                  n_cols: int = 10, init_val=None, max_rounds: int = 16,
@@ -165,11 +166,16 @@ class ClusterStarEngine:
                  net: Network | None = None, n_slabs: int = 4,
                  secondary: bool | None = None, kernel: str = "jnp"):
         assert "part" in mesh.axis_names
-        assert kernel in ("jnp", "pallas"), kernel
+        # the engine's programs rely on compiler-propagated shardings
+        # (GSPMD): a mesh with Explicit axes (jax.make_mesh's default)
+        # would demand an out_sharding on every gather over the blocks
+        mesh = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         # "pallas" rides the fused kernels everywhere index maintenance /
         # OCC rounds run: the sharded partitioned phase, the single-master
         # phase on the full copy, and every partial-replica replay —
-        # bit-identical results either way (interpreted off-TPU)
+        # bit-identical results either way (interpret mode only)
+        check_kernel(kernel)
         self.kernel = kernel
         self.mesh = mesh
         self.n_nodes = int(mesh.shape["part"])
@@ -189,6 +195,10 @@ class ClusterStarEngine:
                else jnp.zeros((self.P, self.R, self.C), jnp.int32))
         tid = jnp.zeros((self.P, self.R), jnp.uint32)
         self._shard = NamedSharding(mesh, P("part"))
+        self._bcast = NamedSharding(mesh, P())
+        self.prog = MeshPrograms(mesh, n_partitions, rows_per_partition,
+                                 n_cols, self.index_specs, max_rounds,
+                                 kernel)
         # f=1 asymmetric replication, physically: the full replica lives on
         # the DESIGNATED MASTER's device only (node 0) — replicating it
         # across the mesh would execute the op replay and the whole
@@ -242,143 +252,9 @@ class ClusterStarEngine:
         # read-tier watermark: the fence epoch the committed snapshot
         # (``_snap``) corresponds to — 0 until the first commit
         self.committed_epoch = 0
-        self._build()
-        self._snap = self._state()
-
-    def _build(self):
-        mesh = self.mesh
-        ppn, R, C, N = self.ppn, self.R, self.C, self.n_nodes
-        has_index = self.has_index
-        kernel = self.kernel
-
-        def part_phase(val, tid, index, seq, ptxn, epoch):
-            # NO collectives inside: single-partition txns need none (§4.1).
-            # part_ids map this block's local segment rows to their global
-            # partition ids so index maintenance lands on the right keys.
-            pid = jax.lax.axis_index("part")
-            part_ids = pid * ppn + jnp.arange(ppn, dtype=jnp.int32)
-            v, t, out, stats = run_partitioned(
-                val, tid, ptxn, epoch, seq0=seq,
-                index=index if has_index else None, part_ids=part_ids,
-                kernel=kernel)
-            idx = out.get("index", index)
-            extras = jnp.stack([stats["committed"],
-                                stats["consume_skips"],
-                                stats["index_overflow"],
-                                stats["user_aborts"]])[None]
-            return (v, t, idx, out["seq"], out["log"], out["committed"],
-                    extras)
-
-        pspec = P("part")
-        txn_spec = {k: P("part") for k in
-                    ("valid", "row", "kind", "delta", "user_abort")}
-        idx_spec = [{k: P("part") for k in ("key", "prow", "tid")}
-                    for _ in self.index_specs]
-        log_keys = ["row", "val", "tid", "write", "kind", "delta"]
-        if has_index:
-            log_keys += ["iwrite", "cskip"]
-        log_spec = {k: P("part") for k in log_keys}
-        self._part = jax.jit(shard_map(
-            part_phase, mesh,
-            in_specs=(pspec, pspec, idx_spec, pspec, txn_spec, P()),
-            out_specs=(pspec, pspec, idx_spec, pspec, log_spec, pspec,
-                       pspec)))
-        self._bcast = NamedSharding(mesh, P())
         self._seq0 = jax.device_put(jnp.zeros((self.P,), jnp.uint32),
                                     self._shard)
-
-        def fence(commit_counts):
-            # §4.3: nodes exchange commit statistics; the psum is the barrier
-            return jax.lax.psum(commit_counts, "part")
-
-        self._fence_barrier = jax.jit(shard_map(
-            fence, mesh, in_specs=(P("part"),), out_specs=P()))
-
-        # single-master phase runs on the master's device only (its full
-        # copy lives there) — no 2PC, no cross-device coordination during
-        # execution; the write stream ships back through the scatters
-        self._sm = jax.jit(
-            lambda v, t, idx, txns, epoch: run_single_master(
-                v, t, txns, epoch, max_rounds=self.max_rounds,
-                index=idx if has_index else None, kernel=kernel))
-
-        def scatter_back(part_val, part_tid, rows, vals, tids):
-            """Apply the master's write stream to the partition owners:
-            each device filters the global stream to its own row range."""
-            pid = jax.lax.axis_index("part")
-            lo = pid * ppn * R
-            local = (rows >= lo) & (rows < lo + ppn * R)
-            lrows = jnp.where(local, rows - lo, -1)
-            v, t, _ = repl.thomas_apply(part_val.reshape(ppn * R, C),
-                                        part_tid.reshape(ppn * R),
-                                        lrows, vals, tids)
-            return v.reshape(ppn, R, C), t.reshape(ppn, R)
-
-        self._scatter = jax.jit(shard_map(
-            scatter_back, mesh,
-            in_specs=(pspec, pspec, P(), P(), P()),
-            out_specs=(pspec, pspec)))
-
-        def scatter_back_sec(sec_val, sec_tid, rows, vals, tids):
-            """Same stream, delivered to each block's SECONDARY home: node
-            m's sec block holds node (m-1)'s partitions (home-major)."""
-            pid = jax.lax.axis_index("part")
-            lo = jnp.mod(pid - 1, N) * ppn * R
-            local = (rows >= lo) & (rows < lo + ppn * R)
-            lrows = jnp.where(local, rows - lo, -1)
-            v, t, _ = repl.thomas_apply(sec_val.reshape(ppn * R, C),
-                                        sec_tid.reshape(ppn * R),
-                                        lrows, vals, tids)
-            return v.reshape(ppn, R, C), t.reshape(ppn, R)
-
-        self._scatter_sec = jax.jit(shard_map(
-            scatter_back_sec, mesh,
-            in_specs=(pspec, pspec, P(), P(), P()),
-            out_specs=(pspec, pspec)))
-
-        # ordered op-stream replay onto the full replica — jitted once; an
-        # eager form here would retrace EVERY slab (host-bound).  One slab
-        # = one jitted replay of its slot range (records + index ops).
-        self._replay_full = jax.jit(
-            lambda v, t, log, idx: repl.replay_partitioned(
-                v, t, log, idx if has_index else None, kernel=kernel))
-
-        part_ids_sec = (jnp.arange(self.P, dtype=jnp.int32) - ppn) \
-            % self.P
-
-        def replay_sec(v, t, log, idx):
-            # the roll IS the ship: each block's ordered stream moves to
-            # its secondary home (a collective permute on the mesh)
-            rl = jax.tree.map(lambda a: jnp.roll(a, ppn, axis=0), log)
-            return repl.replay_partitioned(
-                v, t, rl, idx if has_index else None,
-                part_ids=part_ids_sec, kernel=kernel)
-
-        self._replay_sec = jax.jit(replay_sec)
-
-        if has_index:
-            def sm_idx_replay(idx, kinds, delta, iwrite, tids):
-                pid = jax.lax.axis_index("part")
-                part_ids = pid * ppn + jnp.arange(ppn, dtype=jnp.int32)
-                return repl.replay_index_rounds(idx, kinds, delta, iwrite,
-                                                tids, part_ids=part_ids,
-                                                kernel=kernel)
-
-            def sm_idx_replay_sec(idx, kinds, delta, iwrite, tids):
-                pid = jax.lax.axis_index("part")
-                part_ids = jnp.mod(
-                    pid * ppn + jnp.arange(ppn, dtype=jnp.int32) - ppn,
-                    self.P)
-                return repl.replay_index_rounds(idx, kinds, delta, iwrite,
-                                                tids, part_ids=part_ids,
-                                                kernel=kernel)
-
-            bspecs = (idx_spec, P(), P(), P(), P())
-            self._sm_idx_replay = jax.jit(shard_map(
-                sm_idx_replay, mesh, in_specs=bspecs, out_specs=idx_spec))
-            self._sm_idx_replay_sec = jax.jit(shard_map(
-                sm_idx_replay_sec, mesh, in_specs=bspecs,
-                out_specs=idx_spec))
+        self._snap = self._state()
 
     # ------------------------------------------------------------------
     @property
@@ -441,7 +317,7 @@ class ClusterStarEngine:
             with tr.span("cluster.slab_execute", cat="phase",
                          epoch=self.epoch, slab=s,
                          txns=bounds[s + 1] - bounds[s]):
-                pv, pt, pidx, seq, log, comm, extras = self._part(
+                pv, pt, pidx, seq, log, comm, extras = self.prog.part(
                     pv, pt, pidx, seq, slab, epoch_u)
             if s > 0:
                 # previous slab's stream ships while THIS slab executes
@@ -493,7 +369,7 @@ class ClusterStarEngine:
 
         # ---- fence 1 (commit-statistics psum barrier) --------------------
         tf0 = time.perf_counter()
-        node_counts = self._fence_barrier(
+        node_counts = self.prog.fence_barrier(
             jnp.asarray(counts[:, 0], jnp.int32))
         n_single = int(node_counts[0])
         tr.complete("fence.psum", "fence", tf0, time.perf_counter(),
@@ -515,8 +391,8 @@ class ClusterStarEngine:
         if B > 0:
             flat_v = self.full_val.reshape(self.P * self.R, self.C)
             flat_t = self.full_tid.reshape(self.P * self.R)
-            fv, ft, out, sstats = self._sm(flat_v, flat_t, self.full_idx,
-                                           cross, epoch_u)
+            fv, ft, out, sstats = self.prog.sm(flat_v, flat_t,
+                                               self.full_idx, cross, epoch_u)
             jax.block_until_ready(fv)
             n_cross = int(sstats["committed"])
             self.full_val = fv.reshape(self.P, self.R, self.C)
@@ -916,9 +792,163 @@ class ClusterStarEngine:
         T = ptxn["row"].shape[1]
         bounds = self._slab_bounds(T)
         slab = jax.tree.map(lambda a: a[:, bounds[0]:bounds[1]], ptxn)
-        txt = self._part.lower(self.part_val, self.part_tid, self.part_idx,
-                               self._seq0, slab,
-                               jnp.uint32(1)).compile().as_text()
+        txt = self.prog.part.lower(self.part_val, self.part_tid,
+                                   self.part_idx, self._seq0, slab,
+                                   jnp.uint32(1)).compile().as_text()
         return not any(op in txt for op in
                        ("all-reduce(", "all-gather(", "collective-permute(",
                         "all-to-all(", "reduce-scatter("))
+
+
+class MeshPrograms:
+    """The cluster engine's jitted programs over one ``part`` mesh.  They
+    are built from the mesh and the table shapes alone, with no device
+    state, so the same programs can be lowered for a described topology
+    (``tests/test_tpu_compile.py``)."""
+
+    def __init__(self, mesh, n_partitions: int, rows_per_partition: int,
+                 n_cols: int, index_specs, max_rounds: int, kernel: str):
+        N = int(mesh.shape["part"])
+        ppn, R, C = n_partitions // N, rows_per_partition, n_cols
+        has_index = bool(index_specs)
+        pspec = P("part")
+        # block m -> node m+1: each node's block moves to its secondary
+        # home over the interconnect (ICI on a TPU slice)
+        home_perm = [(m, (m + 1) % N) for m in range(N)]
+
+        def ship_home(tree):
+            return jax.tree.map(
+                lambda a: jax.lax.ppermute(a, "part", home_perm), tree)
+
+        self.ship_home = jax.jit(shard_map(
+            ship_home, mesh, in_specs=(pspec,), out_specs=pspec))
+
+        def part_phase(val, tid, index, seq, ptxn, epoch):
+            # NO collectives inside: single-partition txns need none (§4.1).
+            # part_ids map this block's local segment rows to their global
+            # partition ids so index maintenance lands on the right keys.
+            pid = jax.lax.axis_index("part")
+            part_ids = pid * ppn + jnp.arange(ppn, dtype=jnp.int32)
+            v, t, out, stats = run_partitioned(
+                val, tid, ptxn, epoch, seq0=seq,
+                index=index if has_index else None, part_ids=part_ids,
+                kernel=kernel)
+            idx = out.get("index", index)
+            extras = jnp.stack([stats["committed"],
+                                stats["consume_skips"],
+                                stats["index_overflow"],
+                                stats["user_aborts"]])[None]
+            return (v, t, idx, out["seq"], out["log"], out["committed"],
+                    extras)
+
+        txn_spec = {k: P("part") for k in
+                    ("valid", "row", "kind", "delta", "user_abort")}
+        idx_spec = [{k: P("part") for k in ("key", "prow", "tid")}
+                    for _ in index_specs]
+        log_keys = ["row", "val", "tid", "write", "kind", "delta"]
+        if has_index:
+            log_keys += ["iwrite", "cskip"]
+        log_spec = {k: P("part") for k in log_keys}
+        self.part = jax.jit(shard_map(
+            part_phase, mesh,
+            in_specs=(pspec, pspec, idx_spec, pspec, txn_spec, P()),
+            out_specs=(pspec, pspec, idx_spec, pspec, log_spec, pspec,
+                       pspec)))
+
+        def fence(commit_counts):
+            # §4.3: nodes exchange commit statistics; the psum is the barrier
+            return jax.lax.psum(commit_counts, "part")
+
+        self.fence_barrier = jax.jit(shard_map(
+            fence, mesh, in_specs=(P("part"),), out_specs=P()))
+
+        # single-master phase runs on the master's device only (its full
+        # copy lives there) — no 2PC, no cross-device coordination during
+        # execution; the write stream ships back through the scatters
+        self.sm = jax.jit(
+            lambda v, t, idx, txns, epoch: run_single_master(
+                v, t, txns, epoch, max_rounds=max_rounds,
+                index=idx if has_index else None, kernel=kernel))
+
+        def scatter_back(part_val, part_tid, rows, vals, tids):
+            """Apply the master's write stream to the partition owners:
+            each device filters the global stream to its own row range."""
+            pid = jax.lax.axis_index("part")
+            lo = pid * ppn * R
+            local = (rows >= lo) & (rows < lo + ppn * R)
+            lrows = jnp.where(local, rows - lo, -1)
+            v, t, _ = repl.thomas_apply(part_val.reshape(ppn * R, C),
+                                        part_tid.reshape(ppn * R),
+                                        lrows, vals, tids)
+            return v.reshape(ppn, R, C), t.reshape(ppn, R)
+
+        self.scatter = jax.jit(shard_map(
+            scatter_back, mesh,
+            in_specs=(pspec, pspec, P(), P(), P()),
+            out_specs=(pspec, pspec)))
+
+        def scatter_back_sec(sec_val, sec_tid, rows, vals, tids):
+            """Same stream, delivered to each block's SECONDARY home: node
+            m's sec block holds node (m-1)'s partitions (home-major)."""
+            pid = jax.lax.axis_index("part")
+            lo = jnp.mod(pid - 1, N) * ppn * R
+            local = (rows >= lo) & (rows < lo + ppn * R)
+            lrows = jnp.where(local, rows - lo, -1)
+            v, t, _ = repl.thomas_apply(sec_val.reshape(ppn * R, C),
+                                        sec_tid.reshape(ppn * R),
+                                        lrows, vals, tids)
+            return v.reshape(ppn, R, C), t.reshape(ppn, R)
+
+        self.scatter_sec = jax.jit(shard_map(
+            scatter_back_sec, mesh,
+            in_specs=(pspec, pspec, P(), P(), P()),
+            out_specs=(pspec, pspec)))
+
+        # ordered op-stream replay onto the full replica — jitted once; an
+        # eager form here would retrace EVERY slab (host-bound).  One slab
+        # = one jitted replay of its slot range (records + index ops).
+        self.replay_full = jax.jit(
+            lambda v, t, log, idx: repl.replay_partitioned(
+                v, t, log, idx if has_index else None, kernel=kernel))
+
+        def replay_sec(v, t, log, idx):
+            # the permute IS the ship: each block's ordered stream moves
+            # to its secondary home, which replays it on the copy it hosts
+            # (node m holds node m-1's partitions)
+            pid = jax.lax.axis_index("part")
+            part_ids = jnp.mod((pid - 1) * ppn
+                               + jnp.arange(ppn, dtype=jnp.int32),
+                               n_partitions)
+            v, t, idx_out = repl.replay_partitioned(
+                v, t, ship_home(log),
+                idx if has_index else None, part_ids=part_ids,
+                kernel=kernel)
+            return v, t, (idx_out if has_index else idx)
+
+        self.replay_sec = jax.jit(shard_map(
+            replay_sec, mesh, in_specs=(pspec, pspec, pspec, idx_spec),
+            out_specs=(pspec, pspec, idx_spec)))
+
+        if has_index:
+            def sm_idx_replay(idx, kinds, delta, iwrite, tids):
+                pid = jax.lax.axis_index("part")
+                part_ids = pid * ppn + jnp.arange(ppn, dtype=jnp.int32)
+                return repl.replay_index_rounds(idx, kinds, delta, iwrite,
+                                                tids, part_ids=part_ids,
+                                                kernel=kernel)
+
+            def sm_idx_replay_sec(idx, kinds, delta, iwrite, tids):
+                pid = jax.lax.axis_index("part")
+                part_ids = jnp.mod(
+                    pid * ppn + jnp.arange(ppn, dtype=jnp.int32) - ppn,
+                    n_partitions)
+                return repl.replay_index_rounds(idx, kinds, delta, iwrite,
+                                                tids, part_ids=part_ids,
+                                                kernel=kernel)
+
+            bspecs = (idx_spec, P(), P(), P(), P())
+            self.sm_idx_replay = jax.jit(shard_map(
+                sm_idx_replay, mesh, in_specs=bspecs, out_specs=idx_spec))
+            self.sm_idx_replay_sec = jax.jit(shard_map(
+                sm_idx_replay_sec, mesh, in_specs=bspecs,
+                out_specs=idx_spec))

@@ -39,6 +39,32 @@ from repro.obs import trace as obs
 from repro.storage import IndexSpec, StorageEngine
 
 
+# why kernel="pallas" is refused on a TPU backend: each fused kernel hits
+# a construct the Pallas TPU lowering rejects (compiled for a described
+# v5e at TPC-C widths)
+PALLAS_TPU_BLOCKERS = (
+    "the OCC lock build scatter-mins (unimplemented primitive: scatter-min)",
+    "the OCC install scatters (unimplemented primitive: scatter)",
+    "scan_window loads its scalar-prefetch refs as vectors (SMEM holds "
+    "scalars only)",
+    "index_merge's (1, capP) and (1, Kd) blocks break the (8, 128) tiling "
+    "rule",
+)
+
+
+def check_kernel(kernel: str) -> None:
+    """Validate an engine's ``kernel`` choice at construction.  The fused
+    Pallas kernels run in interpret mode only; on a TPU backend they would
+    fail deep inside the first compile, so refuse them here instead of
+    falling back to the jnp path or the interpreter."""
+    if kernel not in ("jnp", "pallas"):
+        raise ValueError(f"kernel must be 'jnp' or 'pallas', not {kernel!r}")
+    if kernel == "pallas" and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "kernel='pallas' does not lower for TPU: "
+            + "; ".join(PALLAS_TPU_BLOCKERS) + ". Use kernel='jnp'.")
+
+
 @dataclass
 class EngineStats:
     epochs: int = 0
@@ -113,7 +139,8 @@ class StarEngine:
                  kernel: str = "jnp", strict_index: bool = False,
                  durability=None, n_slabs: int = 4):
         """kernel: "jnp" (reference executors) or "pallas" (fused OCC
-        kernels, interpreted off-TPU) — bit-identical results either way.
+        kernels, interpret mode only; refused on a TPU backend, see
+        ``check_kernel``) — bit-identical results either way.
         strict_index: raise instead of counting when an ordered-index
         segment overflows its capacity (silently dropping the largest key
         otherwise — see storage.index.segment_apply).
@@ -131,7 +158,7 @@ class StarEngine:
         accounting)."""
         P, R, C = n_partitions, rows_per_partition, n_cols
         self.P, self.R, self.C = P, R, C
-        assert kernel in ("jnp", "pallas"), kernel
+        check_kernel(kernel)
         self.kernel = kernel
         self.strict_index = strict_index
         self.store = StorageEngine(P, R, C, init_val=init_val,

@@ -27,8 +27,10 @@ Free slots are canonical (SENTINEL, 0, 0) and the dropped-live-key
 overflow is ``max(n_live + n_ins - cap, 0)`` exactly as the oracle counts
 it — bit-identical by tests/test_occ_kernels.py's hypothesis sweep.
 
-Runs under ``interpret=True`` off-TPU (the tier-1/CI path); the in-kernel
+Runs under ``interpret=True`` only (the tier-1/CI path); the in-kernel
 hole scatter is the same ``.at[].max`` primitive the OCC lock kernel uses.
+It does not lower for a TPU yet: the ``(1, capP)`` / ``(1, Kd)`` blocks
+break the (8, 128) tiling rule.
 """
 from __future__ import annotations
 
@@ -137,7 +139,7 @@ def _round_up(x, m):
 
 @functools.partial(jax.jit, static_argnames=("block_slots", "interpret"))
 def index_merge_pallas(key, prow, tid, del_key, ins_key, ins_prow, ins_tid,
-                       *, block_slots=None, interpret=True):
+                       *, interpret: bool, block_slots=None):
     """Batched fused merge: one launch over all P segments.
 
     key/prow/tid: (P, cap) sorted canonical segments.  del_key: (P, Kd)

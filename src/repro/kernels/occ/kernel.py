@@ -36,11 +36,15 @@ Two kernels cover the single-master hot path (ROADMAP "Pallas OCC kernels"):
   ``min`` is commutative and winner rows are unique, so the tiling is
   bit-identical to the former monolithic launch for every block size.
 
-Both kernels run under ``interpret=True`` on CPU (the tier-1/CI path — no
-TPU in the container) with auto single-tile blocks, and are bit-identical
-to ``ref.py`` by construction; ``tests/test_occ_kernels.py`` enforces this
-on random op batches including lock-conflict and phantom-abort
-interleavings, with forced multi-tile grids.
+Both kernels run under ``interpret=True`` only (the tier-1/CI path) with
+auto single-tile blocks, and are bit-identical to ``ref.py`` by
+construction; ``tests/test_occ_kernels.py`` enforces this on random op
+batches including lock-conflict and phantom-abort interleavings, with
+forced multi-tile grids.  Neither lowers for a TPU yet: the lock build and
+the install scatter (scatter-min / scatter have no Pallas TPU lowering),
+the scan window reads its scalar-prefetch refs as vectors (SMEM holds
+scalars), and the validate launch keeps the whole ``(N, C)`` table
+resident in VMEM.  Callers pass ``interpret`` explicitly.
 """
 from __future__ import annotations
 
@@ -70,10 +74,10 @@ def _scan_window_kernel(q_ref, base_ref, cap_ref, key_ref, tid_ref,
     ft = tid_ref[...]                                  # (S,) uint32, resident
     # per-query streams live in SMEM (scalar prefetch): slice this grid
     # step's block
-    sl = (pl.dslice(t * block_q, block_q),)
-    q = pl.load(q_ref, sl)                             # (block_q,) query keys
-    base = pl.load(base_ref, sl)                       # (block_q,) seg starts
-    cap = pl.load(cap_ref, sl)                         # (block_q,) seg lens
+    sl = pl.ds(t * block_q, block_q)
+    q = q_ref[sl]                                      # (block_q,) query keys
+    base = base_ref[sl]                                # (block_q,) seg starts
+    cap = cap_ref[sl]                                  # (block_q,) seg lens
 
     # vectorized lower bound: pos = first slot with seg[pos] >= q
     lo = jnp.zeros(q.shape, jnp.int32)
@@ -101,7 +105,7 @@ def _scan_window_kernel(q_ref, base_ref, cap_ref, key_ref, tid_ref,
                    static_argnames=("n_slots", "n_iters", "interpret",
                                     "block_q"))
 def scan_window_pallas(flat_key, flat_tid, q, seg_base, seg_cap, *,
-                       n_slots: int, n_iters: int, interpret: bool = True,
+                       n_slots: int, n_iters: int, interpret: bool,
                        block_q: int | None = None):
     """flat_key/flat_tid: (S,) concatenated sorted segments; q/seg_base/
     seg_cap: (Q,) per-query key, segment start offset and segment length.
@@ -320,7 +324,7 @@ def _install(val, tidw, wrows, newf, wtids, *, block_rows, interpret):
                                     "block_nt", "block_b", "block_rows"))
 def occ_round_pallas(val, tidw, rows, kind, delta_v, wmask, amask, active,
                      epoch_arr, last_tid, ix_args=None, *, NT: int,
-                     deterministic: bool = False, interpret: bool = True,
+                     interpret: bool, deterministic: bool = False,
                      block_nt: int | None = None, block_b: int | None = None,
                      block_rows: int | None = None):
     """One OCC round as the lock-build → validate → install pipeline.

@@ -39,8 +39,8 @@ def _merge_kernel(rows_ref, vals_ref, tids_ref, val_ref, tid_ref,
     def body(c, carry):
         best_tid, best_idx = carry
         off = c * block_k
-        rows = pl.load(rows_ref, (pl.dslice(off, block_k),))       # (Bk,)
-        tids = pl.load(tids_ref, (pl.dslice(off, block_k),))       # (Bk,)
+        rows = rows_ref[pl.ds(off, block_k)]                       # (Bk,)
+        tids = tids_ref[pl.ds(off, block_k)]                       # (Bk,)
         local = rows - row0                                        # (Bk,)
         in_tile = (local >= 0) & (local < block_rows)
         # (Bk, R) match matrix: does write j target local row r?
@@ -65,8 +65,8 @@ def _merge_kernel(rows_ref, vals_ref, tids_ref, val_ref, tid_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_k",
                                              "interpret"))
-def thomas_merge_pallas(val, tidw, wrows, wvals, wtids, *, block_rows=256,
-                        block_k=256, interpret=False):
+def thomas_merge_pallas(val, tidw, wrows, wvals, wtids, *, interpret: bool,
+                        block_rows=256, block_k=256):
     """val: (N, C) int32; tidw: (N,) uint32; wrows/(K,), wvals/(K,C),
     wtids/(K,).  N % block_rows == 0 and K % block_k == 0 (ops.py pads)."""
     N, C = val.shape

@@ -12,6 +12,15 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes, devices):
+    # Auto axes: the model code relies on compiler-propagated shardings
+    # (GSPMD); jax.make_mesh's default Explicit axes would demand an
+    # out_sharding on every gather over a sharded dim
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,11 +33,11 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} — the "
             "dry-run entrypoint must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return _make_mesh(shape, axes, devices)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many local devices exist (tests / examples)."""
     n = data * model
     devices = jax.devices()[:n]
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices)
+    return _make_mesh((data, model), ("data", "model"), devices)

@@ -109,3 +109,27 @@ def test_engine_adaptive_epoch_flag():
     for _ in range(40):
         eng.controller.observe_latency(30.0, 35.0)
     assert eng.controller.e_ms > 15.0
+
+
+def _star(kernel):
+    return StarEngine(2, 8, kernel=kernel)
+
+
+def _cluster(kernel):
+    import jax
+    from repro.core.cluster import ClusterStarEngine
+    return ClusterStarEngine(jax.make_mesh((1,), ("part",)), 2, 8,
+                             kernel=kernel)
+
+
+@pytest.mark.parametrize("make", [_star, _cluster], ids=["star", "cluster"])
+def test_pallas_kernel_refused_on_tpu_backend(monkeypatch, make):
+    """The fused kernels do not lower for a TPU: on that backend the engine
+    refuses them at construction (naming the failing lowerings) instead
+    of failing inside a compile or falling back to the jnp path."""
+    import jax
+    assert make("pallas").kernel == "pallas"      # interpret mode off-TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="scatter-min"):
+        make("pallas")
+    assert make("jnp").kernel == "jnp"

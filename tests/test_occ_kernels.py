@@ -389,9 +389,11 @@ def test_occ_round_tiled_grid_parity(seed):
             last_tid)
     for det in (False, True):
         for ixa, nt in ((None, N), (ix, NT)):
-            base = occ_round_pallas(*args, ixa, NT=nt, deterministic=det)
+            base = occ_round_pallas(*args, ixa, NT=nt, deterministic=det,
+                                    interpret=True)
             tiled = occ_round_pallas(*args, ixa, NT=nt, deterministic=det,
-                                     block_nt=8, block_b=4, block_rows=16)
+                                     interpret=True, block_nt=8, block_b=4,
+                                     block_rows=16)
             assert all(bool(jnp.array_equal(a, b))
                        for a, b in zip(base, tiled)), (det, nt)
 
@@ -407,7 +409,8 @@ def test_scan_window_block_q_parity():
     q = jnp.asarray(rng.integers(0, 1000, (Q,)), jnp.int32)
     sb = jnp.zeros((Q,), jnp.int32)
     sc = jnp.full((Q,), S, jnp.int32)
-    a = scan_window_pallas(fk, ft, q, sb, sc, n_slots=3, n_iters=7)
+    a = scan_window_pallas(fk, ft, q, sb, sc, n_slots=3, n_iters=7,
+                           interpret=True)
     b = scan_window_pallas(fk, ft, q, sb, sc, n_slots=3, n_iters=7,
-                           block_q=4)
+                           interpret=True, block_q=4)
     assert _tree_equal(tuple(a), tuple(b))
