@@ -145,13 +145,13 @@ class ChangeLog:
             self._fire("on_slab", log, info)
         self.slab_hwm += 1
 
-    def publish_master(self, log, kinds=None, delta=None):
+    def publish_master(self, log, kinds=None, delta=None, epoch=None):
         """Publish the single-master phase's stream: the round-ordered
         value/index log plus the batch's static op arrays (index-op
         replay and WAL recovery re-apply (kind, operand), which the log
-        itself does not carry)."""
+        itself does not carry).  ``epoch`` labels the ship span."""
         self._master = {"log": log, "kinds": kinds, "delta": delta}
-        with obs.span("changelog.master_ship", cat="ship",
+        with obs.span("changelog.master_ship", cat="ship", epoch=epoch,
                       subscribers=len(self._subs)):
             self._fire("on_master", self._master)
 
@@ -170,15 +170,17 @@ class ChangeLog:
         return self._plog_cache
 
     # -- byte attribution (the single source) ----------------------------
-    def attribute(self, batch, plog, has_index: bool, pad_fn) -> Attribution:
+    def attribute(self, batch, plog, has_index: bool, pad_fn,
+                  epoch=None) -> Attribution:
         """Attribute one epoch's partitioned-stream bytes: per-slab sizes
         on the same ``slab_bounds`` frame, the overlapped/fence split, and
         the index-op share.  All zeros when the batch carries no byte
-        tables (see ``repl.epoch_stream_bytes``)."""
+        tables (see ``repl.epoch_stream_bytes``).  ``epoch`` labels the
+        accounting's wait span."""
         # deferred: repro.core.engine imports this module at its top level
         from repro.core import replication as repl
         vb_alt, slab_bytes, ib = repl.epoch_stream_bytes(
-            batch, plog, has_index, self.n_slabs, pad_fn)
+            batch, plog, has_index, self.n_slabs, pad_fn, epoch)
         head, tail = repl.split_overlapped(slab_bytes)
         return Attribution(value_bytes_alt=vb_alt, slab_bytes=slab_bytes,
                            index_op_bytes=ib, overlapped=head, fence=tail)

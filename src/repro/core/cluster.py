@@ -64,7 +64,8 @@ from repro.baselines.cost_model import Network
 from repro.changelog.log import ChangeLog
 from repro.compat import shard_map
 from repro.core import replication as repl
-from repro.core.engine import EngineStats, check_kernel
+from repro.core.engine import (SM_STATS, EngineStats, check_kernel,
+                               to_host, tree_nbytes)
 from repro.core.partitioned import run_partitioned
 from repro.core.phase_switch import PhaseController
 from repro.core.single_master import run_single_master
@@ -296,11 +297,22 @@ class ClusterStarEngine:
         slab's execution dispatch; returning True at slab s kills the
         epoch mid-stream (a node died during the phase) with slabs
         0..s-1 already shipped: remaining slabs never execute or ship."""
+        with obs.span("engine.epoch", "epoch", epoch=self.epoch) as sp:
+            m = self._run_epoch(batch, ingest, commit, abort_check)
+            if "aborted_at_slab" not in m:
+                sp.set(committed=m["committed_single"]
+                       + m["committed_cross"], commit=commit)
+        return m
+
+    def _run_epoch(self, batch, ingest, commit, abort_check) -> dict:
         tr = obs.get_tracer()
-        t_ep0 = time.perf_counter()
+        e = self.epoch
         epoch_u = jnp.uint32(self.epoch)
-        ptxn = jax.tree.map(jnp.asarray, _pad_pow2(batch["ptxn"], 1))
-        cross = jax.tree.map(jnp.asarray, _pad_pow2(batch["cross"], 0))
+        with tr.span("engine.upload", "host", epoch=e) as sp:
+            ptxn = jax.tree.map(jnp.asarray, _pad_pow2(batch["ptxn"], 1))
+            cross = jax.tree.map(jnp.asarray, _pad_pow2(batch["cross"], 0))
+            if tr.enabled:
+                sp.set(bytes=tree_nbytes((ptxn, cross)))
 
         # ---- partitioned phase: slab-chained execution + streaming ------
         T = ptxn["row"].shape[1]
@@ -311,36 +323,35 @@ class ClusterStarEngine:
                              self._seq0)
         slab_logs, committed_chunks, counts = [], [], None
         aborted_at = None
-        for s in range(S):
-            slab = jax.tree.map(lambda a: a[:, bounds[s]:bounds[s + 1]],
-                                ptxn)
-            with tr.span("cluster.slab_execute", cat="phase",
-                         epoch=self.epoch, slab=s,
-                         txns=bounds[s + 1] - bounds[s]):
-                pv, pt, pidx, seq, log, comm, extras = self.prog.part(
-                    pv, pt, pidx, seq, slab, epoch_u)
-            if s > 0:
-                # previous slab's stream ships while THIS slab executes
-                self.changelog.publish_slab(slab_logs[s - 1], self.epoch)
-            slab_logs.append(log)
-            committed_chunks.append(comm)
-            counts = extras if counts is None else counts + extras
-            if abort_check is not None and abort_check(s):
-                aborted_at = s
-                break
-        t_ingest = 0.0
-        if ingest is not None:       # overlap host ingest with device exec
-            ti = time.perf_counter()
-            ingest()
-            t_ingest = time.perf_counter() - ti
-            tr.complete("service.ingest_overlap", "service", ti,
-                        ti + t_ingest, epoch=self.epoch)
-        tb = time.perf_counter()
-        jax.block_until_ready(pv)
-        t1 = time.perf_counter()
+        with tr.span("engine.partitioned", "phase", epoch=e, slabs=S):
+            for s in range(S):
+                slab = jax.tree.map(lambda a: a[:, bounds[s]:bounds[s + 1]],
+                                    ptxn)
+                with tr.span("cluster.slab_execute", cat="phase",
+                             epoch=e, slab=s,
+                             txns=bounds[s + 1] - bounds[s]):
+                    pv, pt, pidx, seq, log, comm, extras = self.prog.part(
+                        pv, pt, pidx, seq, slab, epoch_u)
+                if s > 0:
+                    # previous slab's stream ships while THIS slab executes
+                    self.changelog.publish_slab(slab_logs[s - 1], self.epoch)
+                slab_logs.append(log)
+                committed_chunks.append(comm)
+                counts = extras if counts is None else counts + extras
+                if abort_check is not None and abort_check(s):
+                    aborted_at = s
+                    break
+            t_ingest = 0.0
+            if ingest is not None:   # overlap host ingest with device exec
+                ti = time.perf_counter()
+                with tr.span("service.ingest_overlap", "service", epoch=e):
+                    ingest()
+                t_ingest = time.perf_counter() - ti
+            tb = time.perf_counter()
+            with tr.span("engine.partitioned.wait", "wait", epoch=e):
+                jax.block_until_ready(pv)
+            t1 = time.perf_counter()
         t_part = max(t1 - t0 - t_ingest, t1 - tb)
-        tr.complete("engine.partitioned", "phase", t0, t1,
-                    epoch=self.epoch, slabs=S)
         self.part_val, self.part_tid, self.part_idx = pv, pt, pidx
 
         if aborted_at is not None:
@@ -351,8 +362,7 @@ class ClusterStarEngine:
                     "slabs_consumed": self._slab_hwm}
 
         # ---- tail ship: the ONLY stream transfer the fence waits on -----
-        with tr.span("fence.tail_ship", cat="fence", epoch=self.epoch,
-                     slab=S - 1):
+        with tr.span("fence.tail_ship", cat="fence", epoch=e, slab=S - 1):
             self.changelog.publish_slab(slab_logs[-1], self.epoch)
         plog = self.changelog.epoch_plog()
         p_committed = (committed_chunks[0] if S == 1 else
@@ -360,8 +370,10 @@ class ClusterStarEngine:
 
         # ---- stream byte attribution (the changelog's single source) ----
         vb = 0
-        attr = self.changelog.attribute(batch, plog, self.has_index,
-                                        lambda a: _pad_pow2(a, 1))
+        with tr.span("engine.accounting", "host", epoch=e, stream="part"):
+            attr = self.changelog.attribute(batch, plog, self.has_index,
+                                            lambda a: _pad_pow2(a, 1),
+                                            epoch=e)
         vb_alt, slab_bytes, ib = (attr.value_bytes_alt, attr.slab_bytes,
                                   attr.index_op_bytes)
         ob = attr.total
@@ -369,15 +381,18 @@ class ClusterStarEngine:
 
         # ---- fence 1 (commit-statistics psum barrier) --------------------
         tf0 = time.perf_counter()
-        node_counts = self.prog.fence_barrier(
-            jnp.asarray(counts[:, 0], jnp.int32))
-        n_single = int(node_counts[0])
-        tr.complete("fence.psum", "fence", tf0, time.perf_counter(),
-                    epoch=self.epoch, tail_bytes=ob_tail)
-        # modeled network: the tail slab drains inside the fence; the head
-        # slabs shipped during execution and surface only as un-hidden
-        # residue (paper: "negligible" — now measurable instead of assumed)
-        t_net1 = repl.fence_net_seconds(self.net, ob_tail, ob_head, t_part)
+        with tr.span("engine.fence", "fence", which=1, epoch=e,
+                     tail_bytes=ob_tail, overlapped_bytes=ob_head):
+            with tr.span("fence.psum", "fence", epoch=e, tail_bytes=ob_tail):
+                node_counts = self.prog.fence_barrier(
+                    jnp.asarray(counts[:, 0], jnp.int32))
+                n_single = int(node_counts[0])
+            # modeled network: the tail slab drains inside the fence; the
+            # head slabs shipped during execution and surface only as
+            # un-hidden residue (paper: "negligible" — now measurable
+            # instead of assumed)
+            t_net1 = repl.fence_net_seconds(self.net, ob_tail, ob_head,
+                                            t_part)
         t_fence1 = time.perf_counter()
 
         # ---- single-master phase on the full copy ------------------------
@@ -388,55 +403,63 @@ class ClusterStarEngine:
         B = int(batch["cross"]["row"].shape[0])
         slog = None
         ib_sm = 0
-        if B > 0:
-            flat_v = self.full_val.reshape(self.P * self.R, self.C)
-            flat_t = self.full_tid.reshape(self.P * self.R)
-            fv, ft, out, sstats = self.prog.sm(flat_v, flat_t,
-                                               self.full_idx, cross, epoch_u)
-            jax.block_until_ready(fv)
-            n_cross = int(sstats["committed"])
-            self.full_val = fv.reshape(self.P, self.R, self.C)
-            self.full_tid = ft.reshape(self.P, self.R)
-            if self.has_index:
-                self.full_idx = out["index"]
-            # publish the master stream: the subscriber value-replicates
-            # the writes back to partition owners and secondary homes (the
-            # device_put broadcast is the value-stream ship, §5) and
-            # replays the index-op rounds on every partial copy
-            slog = out["log"]
-            self.changelog.publish_master(slog, kinds=cross["kind"],
-                                          delta=cross["delta"])
-            if self.has_index:
-                ib_sm = repl.index_op_bytes(slog["iwrite"])
-            if "c_row_bytes" in batch:
-                cw = np.asarray(slog["write"])
-                crb = np.broadcast_to(_pad_pow2(batch["c_row_bytes"], 0),
-                                      cw.shape[1:])
-                vb = int(repl.value_bytes(cw, crb[None]))
-            elif batch.get("row_bytes") is not None:
-                vb = int(repl.value_bytes(np.asarray(slog["write"]),
-                                          batch["row_bytes"][None, None, :]))
-            c_committed = np.asarray(out["committed"])
-            starved = int(sstats["starved"])
-            retries = int(sstats["retries"])
-            aborts = int(sstats["user_aborts"])
-            sm_skips = int(sstats.get("consume_skips", 0))
-            sm_overflow = int(sstats.get("index_overflow", 0))
-        else:
-            n_cross = starved = retries = aborts = 0
-            sm_skips = sm_overflow = 0
-            c_committed = np.zeros(0, bool)
+        with tr.span("engine.single_master", "phase", epoch=e,
+                     rounds=self.max_rounds if B else 0):
+            if B > 0:
+                with tr.span("engine.sm_flatten", "host", epoch=e):
+                    flat_v = self.full_val.reshape(self.P * self.R, self.C)
+                    flat_t = self.full_tid.reshape(self.P * self.R)
+                fv, ft, out, sstats = self.prog.sm(flat_v, flat_t,
+                                                   self.full_idx, cross,
+                                                   epoch_u)
+                with tr.span("engine.single_master.wait", "wait", epoch=e):
+                    jax.block_until_ready(fv)
+                self.full_val = fv.reshape(self.P, self.R, self.C)
+                self.full_tid = ft.reshape(self.P, self.R)
+                if self.has_index:
+                    self.full_idx = out["index"]
+                # publish the master stream: the subscriber value-replicates
+                # the writes back to partition owners and secondary homes
+                # (the device_put broadcast is the value-stream ship, §5)
+                # and replays the index-op rounds on every partial copy
+                slog = out["log"]
+                self.changelog.publish_master(slog, kinds=cross["kind"],
+                                              delta=cross["delta"], epoch=e)
+                with tr.span("engine.accounting", "host", epoch=e,
+                             stream="sm"):
+                    if self.has_index:
+                        ib_sm = repl.index_op_bytes(slog["iwrite"])
+                    if "c_row_bytes" in batch:
+                        cw = np.asarray(slog["write"])
+                        crb = np.broadcast_to(
+                            _pad_pow2(batch["c_row_bytes"], 0), cw.shape[1:])
+                        vb = repl.wait_int(
+                            repl.value_bytes(cw, crb[None]), e)
+                    elif batch.get("row_bytes") is not None:
+                        vb = repl.wait_int(repl.value_bytes(
+                            np.asarray(slog["write"]),
+                            batch["row_bytes"][None, None, :]), e)
+                with tr.span("engine.readback", "host", epoch=e) as sp:
+                    dev = {"sstats": {k: sstats[k] for k in SM_STATS
+                                      if k in sstats},
+                           "c_committed": out["committed"]}
+                    sm_host = to_host(dev)
+                    if tr.enabled:
+                        sp.set(arrays=len(jax.tree.leaves(dev)),
+                               bytes=tree_nbytes(sm_host))
+                sstats = sm_host["sstats"]
+                c_committed = sm_host["c_committed"]
+                n_cross = int(sstats["committed"])
+                starved = int(sstats["starved"])
+                retries = int(sstats["retries"])
+                aborts = int(sstats["user_aborts"])
+                sm_skips = int(sstats.get("consume_skips", 0))
+                sm_overflow = int(sstats.get("index_overflow", 0))
+            else:
+                n_cross = starved = retries = aborts = 0
+                sm_skips = sm_overflow = 0
+                c_committed = np.zeros(0, bool)
         t_sm = time.perf_counter() - t0
-        t_sm_round = t_sm / self.max_rounds if B > 0 else 0.0
-        tr.complete("engine.single_master", "phase", t0, t0 + t_sm,
-                    epoch=self.epoch, rounds=self.max_rounds if B else 0)
-        if tr.enabled and B > 0:
-            # rounds execute inside ONE jitted call; attribute the measured
-            # phase time evenly (the same t_sm_round fig11/fig13 report)
-            for r in range(self.max_rounds):
-                tr.complete("engine.sm_round", "phase",
-                            t0 + r * t_sm_round, t0 + (r + 1) * t_sm_round,
-                            epoch=self.epoch, round=r)
 
         # ---- fence 2: epoch boundary + two-version snapshot --------------
         # the fence's contract is "every outstanding stream applied": wait
@@ -444,37 +467,47 @@ class ClusterStarEngine:
         # is fence time) — otherwise the master device's replay backlog
         # silently delays the NEXT epoch's partitioned phase
         tf2 = time.perf_counter()
-        jax.block_until_ready((self.full_val, self.part_val))
-        tr.complete("fence.replay_drain", "fence", tf2,
-                    time.perf_counter(), epoch=self.epoch)
-        t_net2 = repl.fence_net_seconds(self.net, vb + ib_sm)
-        p_committed = np.asarray(p_committed)                  # (P, T)
-        node_c = p_committed.sum(1).reshape(self.n_nodes, -1).sum(1)
-        # modeled fence wait: the slowest node's phase time sets the fence;
-        # a node's own busy time is proxied by its committed share
-        cmax = int(node_c.max()) if node_c.size else 0
-        wait = (t_part * (1.0 - node_c / cmax) if cmax > 0
-                else np.zeros(self.n_nodes))
-        tau_p = tau_s = 0.0
-        counts_h = np.asarray(counts)
-        n_skips = int(counts_h[:, 1].sum()) + sm_skips
-        n_overflow = int(counts_h[:, 2].sum()) + sm_overflow
-        # partitioned-phase user aborts count too (StarEngine parity)
-        aborts += int(counts_h[:, 3].sum())
-        if commit:
-            self.snapshot_commit()
-            self.epoch += 1
-            self.node_committed += node_c
-            self.node_fence_wait_s += wait
-            self.controller.observe_fence_wait(float(wait.max()) * 1e3)
-            self.controller.observe("partitioned", n_single, t_part)
-            self.controller.observe("single", n_cross, t_sm,
-                                    frac_cross=n_cross
-                                    / max(n_cross + n_single, 1))
-            tau_p, tau_s = self.controller.plan()
+        with tr.span("engine.fence", "fence", which=2, epoch=e,
+                     commit=commit):
+            with tr.span("fence.replay_drain", "wait", epoch=e):
+                jax.block_until_ready((self.full_val, self.part_val))
+            t_net2 = repl.fence_net_seconds(self.net, vb + ib_sm)
+            with tr.span("engine.readback", "host", epoch=e) as sp:
+                dev = {"p_committed": p_committed, "counts": counts}
+                if self.has_index:
+                    dev["p_cskip"] = plog["cskip"]
+                    if B > 0:
+                        dev["c_cskip"] = slog["cskip"]
+                host = to_host(dev)
+                if tr.enabled:
+                    sp.set(arrays=len(jax.tree.leaves(dev)),
+                           bytes=tree_nbytes(host))
+            p_committed = host["p_committed"]                  # (P, T)
+            node_c = p_committed.sum(1).reshape(self.n_nodes, -1).sum(1)
+            # modeled fence wait: the slowest node's phase time sets the
+            # fence; a node's own busy time is proxied by its committed
+            # share
+            cmax = int(node_c.max()) if node_c.size else 0
+            wait = (t_part * (1.0 - node_c / cmax) if cmax > 0
+                    else np.zeros(self.n_nodes))
+            tau_p = tau_s = 0.0
+            counts_h = host["counts"]
+            n_skips = int(counts_h[:, 1].sum()) + sm_skips
+            n_overflow = int(counts_h[:, 2].sum()) + sm_overflow
+            # partitioned-phase user aborts count too (StarEngine parity)
+            aborts += int(counts_h[:, 3].sum())
+            if commit:
+                self.snapshot_commit()
+                self.epoch += 1
+                self.node_committed += node_c
+                self.node_fence_wait_s += wait
+                self.controller.observe_fence_wait(float(wait.max()) * 1e3)
+                self.controller.observe("partitioned", n_single, t_part)
+                self.controller.observe("single", n_cross, t_sm,
+                                        frac_cross=n_cross
+                                        / max(n_cross + n_single, 1))
+                tau_p, tau_s = self.controller.plan()
         t_fence2 = time.perf_counter()
-        tr.complete("engine.fence", "fence", tf2, t_fence2, which=2,
-                    epoch=self.epoch - (1 if commit else 0), commit=commit)
         if commit:
             s = self.stats
             s.epochs += 1
@@ -499,8 +532,7 @@ class ClusterStarEngine:
 
         m = {"committed_single": n_single, "committed_cross": n_cross,
              "tau_p_ms": tau_p, "tau_s_ms": tau_s,
-             "t_part_s": t_part, "t_sm_s": t_sm,
-             "t_sm_round_s": t_sm_round, "t_ingest_s": t_ingest,
+             "t_part_s": t_part, "t_sm_s": t_sm, "t_ingest_s": t_ingest,
              "t_fence1_s": t_fence1, "t_fence2_s": t_fence2,
              "t_fence_net_s": t_net1 + t_net2,
              "op_bytes_overlapped": ob_head, "op_bytes_fence": ob_tail,
@@ -511,12 +543,9 @@ class ClusterStarEngine:
              "node_committed": node_c,
              "node_fence_wait_s": wait}
         if self.has_index:
-            m["p_cskip"] = np.asarray(plog["cskip"])           # (P, T, K)
-            m["c_cskip"] = (np.asarray(slog["cskip"]).any(0)
+            m["p_cskip"] = host["p_cskip"]                     # (P, T, K)
+            m["c_cskip"] = (host["c_cskip"].any(0)
                             if B > 0 else None)                # (B_pad, K)
-        tr.complete("engine.epoch", "epoch", t_ep0, time.perf_counter(),
-                    epoch=self.epoch - (1 if commit else 0),
-                    committed=n_single + n_cross, commit=commit)
         return m
 
     # ------------------------------------------------------------------

@@ -52,6 +52,22 @@ PALLAS_TPU_BLOCKERS = (
 )
 
 
+# the stats the host reads back after an epoch (both engines)
+PART_STATS = ("committed", "user_aborts", "consume_skips", "index_overflow")
+SM_STATS = PART_STATS + ("retries", "starved")
+
+
+def tree_nbytes(tree) -> int:
+    """Bytes held by the arrays of a pytree (a span's ``bytes`` arg)."""
+    return sum(a.nbytes for a in jax.tree.leaves(tree))
+
+
+def to_host(tree):
+    """``tree`` with every array copied to the host, one blocking copy per
+    array (not one batched transfer)."""
+    return jax.tree.map(np.asarray, tree)
+
+
 def check_kernel(kernel: str) -> None:
     """Validate an engine's ``kernel`` choice at construction.  The fused
     Pallas kernels run in interpret mode only; on a TPU backend they would
@@ -247,30 +263,39 @@ class StarEngine:
         hooks host-side batch formation for the *next* epoch here so ingest
         overlaps device execution (double buffering). Its host time is
         reported separately as ``t_ingest_s``."""
+        with obs.span("engine.epoch", "epoch", epoch=self.epoch) as sp:
+            m = self._run_epoch(batch, ingest)
+            sp.set(committed=m["committed_single"] + m["committed_cross"])
+        return m
+
+    def _run_epoch(self, batch, ingest) -> dict:
         tr = obs.get_tracer()
-        t_ep0 = time.perf_counter()
+        e = self.epoch
         epoch_u = jnp.uint32(self.epoch)
-        ptxn = jax.tree.map(jnp.asarray, self._pad_axis(batch["ptxn"], 1))
-        cross = jax.tree.map(jnp.asarray, self._pad_axis(batch["cross"], 0))
+        with tr.span("engine.upload", "host", epoch=e) as sp:
+            ptxn = jax.tree.map(jnp.asarray, self._pad_axis(batch["ptxn"], 1))
+            cross = jax.tree.map(jnp.asarray,
+                                 self._pad_axis(batch["cross"], 0))
+            if tr.enabled:
+                sp.set(bytes=tree_nbytes((ptxn, cross)))
         index = self.store.indexes if self.has_index else None
 
         # ---- partitioned phase (single-partition txns, no CC) ----------
         t0 = time.perf_counter()
-        val, tidw, part_out, pstats = self._jit_part(
-            self.store.val, self.store.tid, ptxn, epoch_u,
-            self.part_seq, index, kernel=self.kernel)
-        t_ingest = 0.0
-        if ingest is not None:       # overlap host ingest with device exec
-            ti = time.perf_counter()
-            ingest()
-            t_ingest = time.perf_counter() - ti
-            tr.complete("service.ingest_overlap", "service", ti,
-                        ti + t_ingest, epoch=self.epoch)
-        tb = time.perf_counter()
-        jax.block_until_ready(val)
-        t1 = time.perf_counter()
-        tr.complete("engine.partitioned", "phase", t0, t1,
-                    epoch=self.epoch)
+        with tr.span("engine.partitioned", "phase", epoch=e):
+            val, tidw, part_out, pstats = self._jit_part(
+                self.store.val, self.store.tid, ptxn, epoch_u,
+                self.part_seq, index, kernel=self.kernel)
+            t_ingest = 0.0
+            if ingest is not None:   # overlap host ingest with device exec
+                ti = time.perf_counter()
+                with tr.span("service.ingest_overlap", "service", epoch=e):
+                    ingest()
+                t_ingest = time.perf_counter() - ti
+            tb = time.perf_counter()
+            with tr.span("engine.partitioned.wait", "wait", epoch=e):
+                jax.block_until_ready(val)
+            t1 = time.perf_counter()
         # device-attributable time: when host ingest outlasts the device the
         # wall clock measures ingest, not the phase — don't let that deflate
         # the t_p estimate feeding Eq. 1-2 (t_ingest_s reports the overlap)
@@ -285,14 +310,17 @@ class StarEngine:
         self.changelog.publish_slab(part_out["log"], self.epoch)
 
         # ---- replication byte accounting, partitioned stream (Fig. 15) --
-        # (host-side np on the write mask: the device is already idle here —
-        # t_part was measured with block_until_ready above — and fence 1
-        # needs the stream bytes to model its network drain; skipped
-        # entirely when the batch carries no byte tables)
+        # (reductions of the write mask, which run on the device behind
+        # the replica's replay just dispatched — the first read back is an
+        # ``engine.accounting.wait``; fence 1 needs the stream bytes to
+        # model its network drain; skipped entirely when the batch carries
+        # no byte tables)
         vb = 0
-        attr = self.changelog.attribute(batch, part_out["log"],
-                                        self.has_index,
-                                        lambda a: self._pad_axis(a, 1))
+        with tr.span("engine.accounting", "host", epoch=e, stream="part"):
+            attr = self.changelog.attribute(batch, part_out["log"],
+                                            self.has_index,
+                                            lambda a: self._pad_axis(a, 1),
+                                            epoch=e)
         vb_alt, slab_bytes, ib = attr.value_bytes_alt, attr.slab_bytes, \
             attr.index_op_bytes
         ob = attr.total                          # incl. index op bytes now
@@ -303,82 +331,96 @@ class StarEngine:
         # on the unshipped tail slab
         t0 = time.perf_counter()
         ob_head, ob_tail = attr.overlapped, attr.fence
-        if self.hybrid:
-            t_net1 = self._fence(ob_tail, overlapped_bytes=ob_head,
-                                 t_exec_s=t_part)
-        else:
-            t_net1 = self._fence(vb_alt)
+        with tr.span("engine.fence", "fence", which=1, epoch=e,
+                     tail_bytes=ob_tail if self.hybrid else vb_alt,
+                     overlapped_bytes=ob_head):
+            if self.hybrid:
+                t_net1 = self._fence(ob_tail, overlapped_bytes=ob_head,
+                                     t_exec_s=t_part)
+            else:
+                t_net1 = self._fence(vb_alt)
         t_fence1 = time.perf_counter()
         t_f1 = t_fence1 - t0
-        tr.complete("engine.fence", "fence", t0, t_fence1, which=1,
-                    epoch=self.epoch, tail_bytes=ob_tail if self.hybrid
-                    else vb_alt, overlapped_bytes=ob_head)
 
         # ---- single-master phase (cross-partition txns, Silo OCC) ------
         t0 = time.perf_counter()
-        flat_val = self.store.val.reshape(self.P * self.R, self.C)
-        flat_tid = self.store.tid.reshape(self.P * self.R)
         B = int(cross["row"].shape[0])
-        if B > 0:
-            fval, ftid, sm_out, sstats = self._jit_sm(
-                flat_val, flat_tid, cross, epoch_u + jnp.uint32(0),
-                max_rounds=self.max_rounds,
-                index=self.store.indexes if self.has_index else None,
-                kernel=self.kernel)
-            jax.block_until_ready(fval)
-            self.store.val = fval.reshape(self.P, self.R, self.C)
-            self.store.tid = ftid.reshape(self.P, self.R)
-            if self.has_index:
-                self.store.indexes = sm_out["index"]
-            # value replication, Thomas write rule (order-free) + the
-            # round-ordered index-maintenance stream — published once,
-            # applied by every subscriber
-            self.changelog.publish_master(
-                sm_out["log"],
-                kinds=cross["kind"] if self.has_index else None,
-                delta=cross["delta"] if self.has_index else None)
-        else:
-            sstats = {"committed": jnp.int32(0), "retries": jnp.int32(0),
-                      "user_aborts": jnp.int32(0), "starved": jnp.int32(0),
-                      "writes": jnp.int32(0)}
+        with tr.span("engine.single_master", "phase", epoch=e,
+                     rounds=self.max_rounds if B else 0):
+            with tr.span("engine.sm_flatten", "host", epoch=e):
+                flat_val = self.store.val.reshape(self.P * self.R, self.C)
+                flat_tid = self.store.tid.reshape(self.P * self.R)
+            if B > 0:
+                fval, ftid, sm_out, sstats = self._jit_sm(
+                    flat_val, flat_tid, cross, epoch_u + jnp.uint32(0),
+                    max_rounds=self.max_rounds,
+                    index=self.store.indexes if self.has_index else None,
+                    kernel=self.kernel)
+                with tr.span("engine.single_master.wait", "wait", epoch=e):
+                    jax.block_until_ready(fval)
+                self.store.val = fval.reshape(self.P, self.R, self.C)
+                self.store.tid = ftid.reshape(self.P, self.R)
+                if self.has_index:
+                    self.store.indexes = sm_out["index"]
+                # value replication, Thomas write rule (order-free) + the
+                # round-ordered index-maintenance stream — published once,
+                # applied by every subscriber
+                self.changelog.publish_master(
+                    sm_out["log"],
+                    kinds=cross["kind"] if self.has_index else None,
+                    delta=cross["delta"] if self.has_index else None,
+                    epoch=e)
+            else:
+                sstats = {"committed": jnp.int32(0), "retries": jnp.int32(0),
+                          "user_aborts": jnp.int32(0),
+                          "starved": jnp.int32(0), "writes": jnp.int32(0)}
         t_sm = time.perf_counter() - t0
-        # per-round kernel time: the single-master phase is max_rounds
-        # identical fused-round launches (one per OCC round)
-        t_sm_round = t_sm / self.max_rounds if B > 0 else 0.0
-        tr.complete("engine.single_master", "phase", t0, t0 + t_sm,
-                    epoch=self.epoch, rounds=self.max_rounds if B else 0)
-        if tr.enabled and B > 0:
-            # the rounds execute inside ONE jitted call; attribute the
-            # measured phase time evenly (the same t_sm_round fig11 reports)
-            for r in range(self.max_rounds):
-                tr.complete("engine.sm_round", "phase",
-                            t0 + r * t_sm_round, t0 + (r + 1) * t_sm_round,
-                            epoch=self.epoch, round=r)
 
         # ---- byte accounting, single-master value stream ----------------
         ib_sm = 0
         if B > 0:
-            cw = np.asarray(sm_out["log"]["write"])            # (rounds,B,M)
-            if "c_row_bytes" in batch:
-                crb = np.broadcast_to(self._pad_axis(batch["c_row_bytes"], 0),
-                                      cw.shape[1:])
-                vb = int(repl.value_bytes(cw, crb[None]))
-            elif batch.get("row_bytes") is not None:
-                vb = int(repl.value_bytes(cw, batch["row_bytes"][None, None, :]))
-            if self.has_index and (vb or ob):
-                # index ops ride the SM stream too — previously uncounted
-                # in the fence's modeled bytes (fence-latency attribution)
-                ib_sm = repl.index_op_bytes(sm_out["log"]["iwrite"])
+            with tr.span("engine.accounting", "host", epoch=e, stream="sm"):
+                cw = np.asarray(sm_out["log"]["write"])        # (rounds,B,M)
+                if "c_row_bytes" in batch:
+                    crb = np.broadcast_to(
+                        self._pad_axis(batch["c_row_bytes"], 0), cw.shape[1:])
+                    vb = repl.wait_int(repl.value_bytes(cw, crb[None]), e)
+                elif batch.get("row_bytes") is not None:
+                    vb = repl.wait_int(repl.value_bytes(
+                        cw, batch["row_bytes"][None, None, :]), e)
+                if self.has_index and (vb or ob):
+                    # index ops ride the SM stream too — previously
+                    # uncounted in the fence's modeled bytes
+                    # (fence-latency attribution)
+                    ib_sm = repl.index_op_bytes(sm_out["log"]["iwrite"])
 
         # ---- fence 2: epoch boundary ------------------------------------
         t0 = time.perf_counter()
-        t_net2 = self._fence(vb + ib_sm, commit_epoch=self.epoch)
-        self.epoch += 1
+        with tr.span("engine.fence", "fence", which=2, epoch=e, commit=True,
+                     value_bytes=vb + ib_sm):
+            t_net2 = self._fence(vb + ib_sm, commit_epoch=self.epoch)
+            self.epoch += 1
         t_fence2 = time.perf_counter()
         t_f2 = t_fence2 - t0
-        tr.complete("engine.fence", "fence", t0, t_fence2, which=2,
-                    epoch=self.epoch - 1, commit=True,
-                    value_bytes=vb + ib_sm)
+
+        # ---- readback: what the host needs from the device -------------
+        with tr.span("engine.readback", "host", epoch=e) as sp:
+            dev = {"pstats": {k: pstats[k] for k in PART_STATS
+                              if k in pstats},
+                   "sstats": {k: sstats[k] for k in SM_STATS
+                              if k in sstats},
+                   "p_committed": part_out["committed"]}
+            if B > 0:
+                dev["c_committed"] = sm_out["committed"]
+            if self.has_index:
+                dev["p_cskip"] = part_out["log"]["cskip"]
+                if B > 0:
+                    dev["c_cskip"] = sm_out["log"]["cskip"]
+            host = to_host(dev)
+            if tr.enabled:
+                sp.set(arrays=len(jax.tree.leaves(dev)),
+                       bytes=tree_nbytes(host))
+        pstats, sstats = host["pstats"], host["sstats"]
 
         # ---- controller telemetry ---------------------------------------
         nc = int(sstats["committed"])
@@ -417,29 +459,25 @@ class StarEngine:
             s.slabs_shipped += len(slab_bytes)
         # per-txn commit outcomes + fence stamps — the service layer maps
         # these back to queued requests (group commit at the epoch fence)
-        p_committed = np.asarray(part_out["committed"])          # (P, T_pad)
-        c_committed = (np.asarray(sm_out["committed"]) if B > 0
-                       else np.zeros(B, bool))                   # (B_pad,)
+        c_committed = host["c_committed"] if B > 0 else np.zeros(B, bool)
         m = {"committed_single": ns, "committed_cross": nc,
              "tau_p_ms": tau_p, "tau_s_ms": tau_s,
              "t_part_s": t_part, "t_sm_s": t_sm,
-             "t_sm_round_s": t_sm_round,
              "t_ingest_s": t_ingest,
              "t_fence1_s": t_fence1, "t_fence2_s": t_fence2,
              "t_fence_net_s": t_net1 + t_net2,
              "op_bytes_overlapped": ob_head if self.hybrid else 0,
              "op_bytes_fence": ob_tail if self.hybrid else vb_alt,
-             "p_committed": p_committed, "c_committed": c_committed,
+             "p_committed": host["p_committed"],                 # (P, T_pad)
+             "c_committed": c_committed,                         # (B_pad,)
              "index_overflow": overflow,
              "starved": int(sstats["starved"])}
         if self.has_index:
             # which consume ops were skipped on EXPECT mismatch — the host
             # mirror (tpcc.apply_consume_feedback) re-queues these districts
-            m["p_cskip"] = np.asarray(part_out["log"]["cskip"])  # (P,T,K)
-            m["c_cskip"] = (np.asarray(sm_out["log"]["cskip"]).any(0)
+            m["p_cskip"] = host["p_cskip"]                       # (P,T,K)
+            m["c_cskip"] = (host["c_cskip"].any(0)
                             if B > 0 else None)                  # (B_pad,K)
-        tr.complete("engine.epoch", "epoch", t_ep0, time.perf_counter(),
-                    epoch=self.epoch - 1, committed=ns + nc)
         return m
 
     # ------------------------------------------------------------------

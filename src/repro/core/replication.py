@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ops import IDX_OPS, apply_op
+from repro.obs import trace as obs
 from repro.storage.index import apply_index_ops
 
 KEY_BYTES = 8
@@ -335,15 +336,25 @@ def fence_net_seconds(net, fence_bytes: int, overlapped_bytes: int = 0,
         + max(0.0, net.transfer_s(overlapped_bytes) - t_exec_s)
 
 
+def wait_int(x, epoch=None) -> int:
+    """``int(x)`` of a byte reduction dispatched to the device, in an
+    ``engine.accounting.wait`` span: the reduction queues behind the
+    replica's apply of the stream just published, so reading it back is
+    where the host waits for that apply."""
+    with obs.span("engine.accounting.wait", "wait", epoch=epoch):
+        return int(x)
+
+
 def epoch_stream_bytes(batch, log, has_index: bool, n_slabs: int,
-                       pad_fn) -> tuple[int, list[int], int]:
+                       pad_fn, epoch=None) -> tuple[int, list[int], int]:
     """One epoch's partitioned-stream byte accounting, shared by both
     engines so their fence models cannot desynchronize.
 
     batch carries either per-op tables (``p_row_bytes``/``p_op_bytes``,
     padded to the log's T via ``pad_fn``) or uniform per-op-slot tables
     (``row_bytes``/``op_bytes``); log is the phase's (P, T, M) write log
-    (with ``iwrite`` when indexes are attached).  Returns
+    (with ``iwrite`` when indexes are attached); ``epoch`` labels the
+    wait span.  Returns
     ``(value_bytes_alt, per_slab_op_bytes, index_op_bytes)`` — all zeros /
     empty when the batch carries no byte tables."""
     has_tables = "p_row_bytes" in batch \
@@ -360,7 +371,7 @@ def epoch_stream_bytes(batch, log, has_index: bool, n_slabs: int,
             np.asarray(batch["row_bytes"])[None, None, :], wmask.shape)
         pob = np.broadcast_to(
             np.asarray(batch["op_bytes"])[None, None, :], wmask.shape)
-    vb_alt = int(value_bytes(wmask, prb))
+    vb_alt = wait_int(value_bytes(wmask, prb), epoch)
     slabs = slab_op_bytes(wmask, pob, iw, n_slabs)
     ib = index_op_bytes(iw) if iw is not None else 0
     return vb_alt, slabs, ib

@@ -1,16 +1,22 @@
 """Low-overhead span tracer exporting Chrome/Perfetto trace_event JSON.
 
-The whole epoch lifecycle — partitioned slabs, fence (tail-ship / psum /
-WAL-sink), single-master rounds, replica replay, recovery — is wired
-with ``with span("engine.partitioned", cat="phase", epoch=e):`` blocks.
+The whole epoch lifecycle — upload, partitioned slabs, fence (tail-ship /
+psum / WAL-sink), single-master phase, replica replay, readback,
+recovery — is wired with ``with span("engine.partitioned", cat="phase",
+epoch=e):`` blocks.
 When tracing is disabled (the default) each such block costs one method
 call returning a shared null context manager; the budget is asserted in
 ``tests/test_obs.py`` (≤2% of measured epoch time).
 
 Spans record ``time.perf_counter()`` begin/end (monotonic), nest per
 thread, and land in a bounded thread-safe ring buffer (drop-oldest with
-a counter).  ``export_chrome(path)`` writes the standard trace_event
-JSON object (``ph:"X"`` complete events, microsecond timestamps) that
+a counter).  Each event carries an ``id`` and the ``parent`` id of the
+innermost span open on its thread when it was recorded, so a layer's
+self time is its duration minus its children's; ``t0_s`` is the
+absolute ``perf_counter`` start, the clock a caller uses to place spans
+beside its own timestamps (a profiler's device trace, for one).
+``export_chrome(path)`` writes the standard trace_event JSON object
+(``ph:"X"`` complete events, microsecond timestamps) that
 https://ui.perfetto.dev and ``chrome://tracing`` load directly.
 
 Kernel-launch hooks: the Pallas dispatch wrappers in ``kernels/occ`` and
@@ -48,7 +54,7 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_id", "_parent")
 
     def __init__(self, tr, name, cat, args):
         self._tr = tr
@@ -57,12 +63,18 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        stack = self._tr._stack()
+        self._parent = stack[-1] if stack else None
+        self._id = next(self._tr._ids)
+        stack.append(self._id)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tr._emit(self.name, self.cat, self._t0, time.perf_counter(),
-                       self.args)
+        t1 = time.perf_counter()
+        self._tr._stack().pop()
+        self._tr._emit(self.name, self.cat, self._t0, t1, self.args,
+                       self._id, self._parent)
         return False
 
     def set(self, **kw):
@@ -85,6 +97,8 @@ class Tracer:
         self._emitted = 0
         self._tids = {}
         self._tid_next = itertools.count()
+        self._ids = itertools.count()
+        self._local = threading.local()
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, cat: str = "", **args):
@@ -109,6 +123,13 @@ class Tracer:
             return
         self._emit(name, cat, t0, max(t1, t0), args or None)
 
+    def _stack(self) -> list:
+        """Ids of the spans open on the calling thread, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _tid(self):
         ident = threading.get_ident()
         tid = self._tids.get(ident)
@@ -116,11 +137,14 @@ class Tracer:
             tid = self._tids[ident] = next(self._tid_next)
         return tid
 
-    def _emit(self, name, cat, t0, t1, args):
+    def _emit(self, name, cat, t0, t1, args, sid=None, parent=None):
+        if sid is None:               # complete()/instant(): a leaf
+            stack = self._stack()
+            parent = stack[-1] if stack else None
+            sid = next(self._ids)
         with self._lock:
-            self._buf.append((name, cat, t0 - self._origin,
-                              None if t1 is None else t1 - t0,
-                              self._tid(), args))
+            self._buf.append((name, cat, t0, None if t1 is None else t1 - t0,
+                              self._tid(), args, sid, parent))
             self._emitted += 1
 
     # -- inspection --------------------------------------------------------
@@ -129,12 +153,17 @@ class Tracer:
         return self._emitted - len(self._buf)
 
     def events(self):
-        """Recorded events as dicts (ts/dur in seconds since enable)."""
+        """Recorded events as dicts: ``ts_s`` seconds since enable (or
+        ``clear``), ``t0_s`` the absolute ``perf_counter`` start, ``dur_s``
+        (None for an instant), ``id`` and ``parent`` (the id of the span
+        open on the thread when the event was recorded, or None)."""
         with self._lock:
             raw = list(self._buf)
-        return [{"name": n, "cat": c, "ts_s": ts, "dur_s": dur,
-                 "tid": tid, "args": args or {}}
-                for n, c, ts, dur, tid, args in raw]
+            origin = self._origin
+        return [{"name": n, "cat": c, "ts_s": t0 - origin, "t0_s": t0,
+                 "dur_s": dur, "tid": tid, "args": args or {},
+                 "id": sid, "parent": parent}
+                for n, c, t0, dur, tid, args, sid, parent in raw]
 
     def clear(self):
         with self._lock:

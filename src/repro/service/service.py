@@ -107,7 +107,8 @@ class TxnService:
         """Pull due arrivals from every client and run admission. New
         arrivals stop at the deadline so the drain phase terminates."""
         until = min(now_s, self._deadline)
-        with obs.span("service.admission", cat="service"):
+        with obs.span("service.admission", cat="service",
+                      epoch=self.engine.epoch):
             for c in self.clients:
                 req = c.pull(until)
                 if req is None:
@@ -205,7 +206,8 @@ class TxnService:
 
         def ingest_hook():
             self._ingest(self.clock())
-            with obs.span("service.batch_form", cat="service"):
+            with obs.span("service.batch_form", cat="service",
+                          epoch=self.engine.epoch):
                 nxt["formed"] = self.batcher.form(self.clock())
             if self.read_tier is not None:
                 # mid-epoch: k=0 serves of partitions below the slab
@@ -234,7 +236,9 @@ class TxnService:
             self.stats.epochs += 1
             if self.feedback is not None:
                 self.feedback(batch, m)
-            self._complete(plan, m)
+            with obs.span("service.complete", cat="service",
+                          epoch=self.engine.committed_epoch):
+                self._complete(plan, m)
             self._observe_epoch(m)
             if self.read_tier is not None:
                 # commit fence passed: refresh the snapshot catalog, then

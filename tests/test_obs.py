@@ -14,7 +14,12 @@
   count stays under 2% of a measured epoch;
 * recovery span tree (subprocess, forced host devices): a mid-run node
   kill exports classify → revert → restore → re-master → re-execute
-  spans, all nested inside one ``recovery`` span.
+  spans, all nested inside one ``recovery`` span;
+* epoch span tree: a served epoch records every host segment of
+  ``run_epoch`` (upload, partitioned dispatch and wait, byte accounting,
+  fences, flatten, single-master dispatch and wait, readback) and the
+  service's retirement of the batch, each with its epoch and its parent,
+  each inside its parent — on ``StarEngine`` and on ``ClusterRuntime``.
 """
 import json
 import os
@@ -26,13 +31,18 @@ from dataclasses import fields
 from pathlib import Path
 
 import numpy as np
+import pytest
 
 from repro.core.engine import StarEngine
 from repro.db import tpcc
 from repro.obs import MetricsRegistry, Tracer, set_tracer
 from repro.obs.trace import get_tracer
 
-SRC = str(Path(__file__).resolve().parents[1] / "src")
+REPO = Path(__file__).resolve().parents[1]
+SRC = str(REPO / "src")
+# the benchmark's reader of the span tree walks it for the tree checks
+sys.path.insert(0, str(REPO / "bench"))
+from starbench import spanclock  # noqa: E402
 
 
 def _run(code: str, devices: int = 4) -> str:
@@ -129,6 +139,29 @@ def test_trace_instants_and_kernel_counts():
     assert kernel_launch_counts()["test.k"] == before + 2
 
 
+def test_events_record_their_parent_and_absolute_start():
+    tr = Tracer(enabled=True)
+    t_before = time.perf_counter()
+    with tr.span("outer", epoch=1):
+        with tr.span("inner"):
+            tr.instant("mark")
+        t0 = time.perf_counter()
+        tr.complete("done", "", t0, t0 + 0.5)
+    tr.complete("alone", "", t0, t0)
+    ev = {e["name"]: e for e in tr.events()}
+    assert ev["outer"]["parent"] is None and ev["alone"]["parent"] is None
+    assert ev["inner"]["parent"] == ev["outer"]["id"]
+    assert ev["mark"]["parent"] == ev["inner"]["id"]
+    assert ev["done"]["parent"] == ev["outer"]["id"]
+    assert len({e["id"] for e in ev.values()}) == len(ev)
+    # t0_s is the perf_counter clock itself; ts_s keeps its origin
+    assert ev["done"]["t0_s"] == t0 and ev["done"]["dur_s"] == 0.5
+    assert t_before <= ev["outer"]["t0_s"] <= ev["inner"]["t0_s"]
+    origin = ev["outer"]["t0_s"] - ev["outer"]["ts_s"]
+    for e in ev.values():
+        assert e["ts_s"] == pytest.approx(e["t0_s"] - origin, abs=1e-9)
+
+
 def test_ring_buffer_bounded_drop_oldest():
     tr = Tracer(capacity=16, enabled=True)
     for i in range(64):
@@ -187,8 +220,6 @@ def test_registry_exporters(tmp_path):
     reg = MetricsRegistry()
     reg.counter_add("a.count", 3)
     reg.gauge_set("a.gauge", 1.5)
-    reg.hist_observe("a.lat_s", 0.004)
-    reg.hist_observe("a.lat_s", 0.3)
     reg.snapshot(0)
     reg.counter_add("a.count", 1)
     reg.snapshot(1)
@@ -197,11 +228,8 @@ def test_registry_exporters(tmp_path):
     lines = [json.loads(ln) for ln in p.read_text().splitlines()]
     assert n == len(lines) == 2
     assert lines[0]["a.count"] == 3 and lines[1]["a.count"] == 4
+    assert lines[0]["a.gauge"] == 1.5
     assert lines[1]["epoch"] == 1
-    txt = reg.export_prometheus()
-    assert "# TYPE a_count gauge" in txt
-    assert 'a_lat_s_bucket{le="+Inf"} 2' in txt
-    assert "a_lat_s_count 2" in txt
 
 
 # ---------------------------------------------------------------------------
@@ -282,3 +310,135 @@ def test_recovery_span_tree_exported():
         assert c["tid"] == root["tid"]
         assert _contained(c, root), (child, c, root)
     assert root["args"]["case"] == "PHASE_SWITCHING"
+
+
+# ---------------------------------------------------------------------------
+# the epoch's span tree: every host segment, with epoch and parent
+# ---------------------------------------------------------------------------
+EPOCH_SPANS = {"engine.epoch", "engine.upload", "engine.partitioned",
+               "service.ingest_overlap", "engine.partitioned.wait",
+               "engine.accounting", "engine.accounting.wait",
+               "engine.fence", "engine.sm_flatten",
+               "engine.single_master", "engine.single_master.wait",
+               "engine.readback", "changelog.slab_ship",
+               "changelog.master_ship", "changelog.commit",
+               "service.complete"}
+
+
+def _epoch_tree(events, want):
+    """The spans of the first served epoch that records every name of
+    ``want``: its ``engine.epoch`` root, the spans below it and
+    the service's ``service.complete`` of the same epoch."""
+    for root in (e for e in events if e["name"] == "engine.epoch"):
+        tree = [root] + spanclock.descendants(events, root)
+        tree += [e for e in events if e["name"] == "service.complete"
+                 and e["args"]["epoch"] == root["args"]["epoch"]]
+        if want <= {e["name"] for e in tree}:
+            return root, tree
+    raise AssertionError(sorted({e["name"] for e in events}))
+
+
+def _check_epoch_tree(events, want=EPOCH_SPANS):
+    root, tree = _epoch_tree(events, want)
+    by_id = {e["id"]: e for e in events}
+    ep = root["args"]["epoch"]
+    assert root["parent"] is None
+    for e in tree:
+        if e["name"].startswith(("engine.", "service.", "changelog.")):
+            assert e["args"]["epoch"] == ep, e
+        if e is root or e["name"] == "service.complete":
+            continue
+        parent = by_id[e["parent"]]
+        if e["dur_s"] is None:                  # an instant inside its span
+            assert parent["t0_s"] <= e["t0_s"] \
+                <= parent["t0_s"] + parent["dur_s"], (e, parent)
+            continue
+        assert parent["t0_s"] <= e["t0_s"], (e, parent)
+        assert e["t0_s"] + e["dur_s"] \
+            <= parent["t0_s"] + parent["dur_s"] + 1e-9, (e, parent)
+    # service.complete follows the engine call it retires
+    done = [e for e in tree if e["name"] == "service.complete"]
+    assert done[0]["t0_s"] >= root["t0_s"] + root["dur_s"]
+    assert {e["args"].get("stream") for e in tree
+            if e["name"] == "engine.accounting"} == {"part", "sm"}
+    readback = next(e for e in tree if e["name"] == "engine.readback")
+    assert readback["args"]["arrays"] > 0 and readback["args"]["bytes"] > 0
+    upload = next(e for e in tree if e["name"] == "engine.upload")
+    assert upload["args"]["bytes"] > 0
+    # each device wait sits in its phase, the accounting's wait for its
+    # reductions in the accounting, and every wait is of category "wait"
+    for wait, phase in (("engine.partitioned.wait", "engine.partitioned"),
+                        ("engine.single_master.wait",
+                         "engine.single_master"),
+                        ("engine.accounting.wait", "engine.accounting")):
+        for w in (e for e in tree if e["name"] == wait):
+            assert by_id[w["parent"]]["name"] == phase
+            assert w["cat"] == "wait"
+    # fence 1 holds the cluster's psum barrier as it holds StarEngine's
+    for e in tree:
+        if e["name"] == "fence.psum":
+            assert by_id[e["parent"]]["args"].get("which") == 1
+    return root, tree
+
+
+def test_served_epoch_records_every_host_segment():
+    from repro.db import ycsb
+    from repro.service import (AdmissionConfig, ClosedLoopClient,
+                               TxnService, YCSBSource)
+    cfg = ycsb.YCSBConfig(n_partitions=4, records_per_partition=256,
+                          cross_ratio=0.25)
+    eng = StarEngine(4, 256)
+    svc = TxnService(eng, [ClosedLoopClient(YCSBSource(cfg, seed=1), 96)],
+                     AdmissionConfig(256, 256), slots_per_partition=16,
+                     master_lanes=16)
+    tracer = Tracer(enabled=True)
+    old = set_tracer(tracer)
+    try:
+        svc.run(duration_s=30.0, max_epochs=3)
+    finally:
+        set_tracer(old)
+    events = tracer.events()
+    root, tree = _check_epoch_tree(events)
+    fences = sorted(e["args"]["which"] for e in tree
+                    if e["name"] == "engine.fence")
+    assert fences == [1, 2]
+    # the service's ingest spans nest under the overlapped ingest
+    ingest = next(e for e in tree if e["name"] == "service.ingest_overlap")
+    assert {e["name"] for e in tree if e["parent"] == ingest["id"]} \
+        >= {"service.admission", "service.batch_form"}
+    # the single-master time is no longer split into synthetic rounds
+    assert not any(e["name"] == "engine.sm_round" for e in events)
+
+
+def test_cluster_epoch_records_the_same_host_segments():
+    out = _run("""
+        import json
+        import jax
+        from repro.cluster import ClusterRuntime, ClusterTxnService
+        from repro.db import ycsb
+        from repro.obs import Tracer, set_tracer
+        from repro.service import (AdmissionConfig, ClosedLoopClient,
+                                   YCSBSource)
+
+        cfg = ycsb.YCSBConfig(n_partitions=8, records_per_partition=128,
+                              cross_ratio=0.25)
+        mesh = jax.make_mesh((4,), ("part",), devices=jax.devices()[:4])
+        rt = ClusterRuntime(mesh, 8, 128)
+        svc = ClusterTxnService(
+            rt, [ClosedLoopClient(YCSBSource(cfg, seed=1), 96)],
+            AdmissionConfig(256, 256), slots_per_partition=16,
+            master_lanes=16)
+        tracer = Tracer(enabled=True)
+        set_tracer(tracer)
+        svc.run(duration_s=60.0, max_epochs=3)
+        print("EVENTS " + json.dumps(tracer.events()))
+    """, devices=4)
+    line = [ln for ln in out.splitlines() if ln.startswith("EVENTS ")][-1]
+    events = json.loads(line[len("EVENTS "):])
+    root, tree = _check_epoch_tree(events)
+    names = {e["name"] for e in tree}
+    assert {"cluster.slab_execute", "fence.tail_ship", "fence.psum",
+            "fence.replay_drain"} <= names
+    drain = next(e for e in tree if e["name"] == "fence.replay_drain")
+    assert drain["cat"] == "wait"
+    assert not any(e["name"] == "engine.sm_round" for e in events)
