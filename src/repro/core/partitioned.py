@@ -96,14 +96,14 @@ def run_partitioned(val, tidw, ptxn, epoch, seq0=None, index=None,
         seq = jnp.where(valid, tidlib.tid_seq(new_tid), seq)
 
         # scatter ONLY write ops (read/padding ops may share a row with a
-        # write in the same txn — a duplicate-index scatter would race)
+        # write in the same txn — a duplicate-index scatter would race);
+        # the others aim past the end at row R and are dropped, so the
+        # table is updated in place (a -1 would wrap even under "drop")
         R = val.shape[1]
         wrows = jnp.where(wmask, rows, R)                               # (P,M)
 
         def commit(v, t, r, n, nt):
-            v = jnp.concatenate([v, jnp.zeros((1, v.shape[1]), v.dtype)])
-            t = jnp.concatenate([t, jnp.zeros((1,), t.dtype)])
-            return v.at[r].set(n)[:R], t.at[r].set(nt)[:R]
+            return v.at[r].set(n, mode="drop"), t.at[r].set(nt, mode="drop")
 
         val, tidw = jax.vmap(commit)(
             val, tidw, wrows, new,

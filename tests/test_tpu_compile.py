@@ -30,6 +30,9 @@ from repro.storage.index import make_index
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
 N_WAREHOUSES, T_SLOTS, LANES, N_SLABS, ROUNDS = 16, 128, 128, 4, 16
+# the ycsb-16p deployment: 16 partitions of 200,000 rows of 25 int32 words,
+# 64 queue slots per partition, 10 ops per transaction
+YCSB_P, YCSB_R, YCSB_C, YCSB_T, YCSB_M = 16, 200_000, 25, 64, 10
 
 
 @pytest.fixture(scope="module")
@@ -141,3 +144,32 @@ def test_cluster_programs_compile_for_v5e_2x2(topo, cfg):
     sec = prog.replay_sec.lower(val, tid, _place(log, shard),
                                 index).compile()
     assert "collective-permute" in _check(sec)
+
+
+def test_partitioned_commit_in_place_for_v5e_ycsb16(topo):
+    """At ycsb-16p's shapes each queue slot scatters into the table in place.
+
+    No whole-table copy or pad runs inside the compiled slot loop, and the
+    program's temporaries stay under 1.5 tables: one relayout of the table,
+    not a padded copy of it per slot.
+    """
+    from _hlo import opcode, ops_of_shape, while_body_instructions
+    P, R, C, T, M = YCSB_P, YCSB_R, YCSB_C, YCSB_T, YCSB_M
+    chip = SingleDeviceSharding(topo.devices[0])
+    ptxn = {"valid": _sds((P, T), jnp.bool_, chip),
+            "row": _sds((P, T, M), jnp.int32, chip),
+            "kind": _sds((P, T, M), jnp.int32, chip),
+            "delta": _sds((P, T, M, C), jnp.int32, chip),
+            "user_abort": _sds((P, T), jnp.bool_, chip)}
+    compiled = jax.jit(run_partitioned).lower(
+        _sds((P, R, C), jnp.int32, chip), _sds((P, R), jnp.uint32, chip),
+        ptxn, _sds((), jnp.uint32, chip),
+        _sds((P,), jnp.uint32, chip)).compile()
+    body = while_body_instructions(_check(compiled))
+    assert any(opcode(line)[1] == "scatter" for line in body)
+    whole = ops_of_shape(body, ("pad", "copy", "copy-start"),
+                         ((P, R, C), (P, R), (P, R + 1, C), (P, R + 1)))
+    assert not whole, whole
+    table_bytes = P * R * C * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.5 * table_bytes, (temp, table_bytes)
