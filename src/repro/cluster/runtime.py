@@ -21,7 +21,9 @@ Composes the pieces the paper's cluster runs as separate processes:
 Failure semantics (simulation contract, see DESIGN.md "Cluster runtime"):
 a node killed during epoch e misses e's fence, so e never commits — the
 runtime runs the doomed epoch to the fence (``commit=False``; its wall
-time is real lost work) or aborts it mid-stream at the killed slab,
+time is real lost work; a ``recovery.doomed`` span holds it, and its
+``engine.epoch`` span carries ``doomed=True``) or aborts it mid-stream at
+the killed slab,
 reverts every replica to epoch e-1 via the two-version snapshots (which
 also discards every stream slab the replicas consumed in-flight — the
 slab high-watermark guarantees the re-executed epoch applies each slab to
@@ -49,6 +51,7 @@ import numpy as np
 
 from repro.cluster.coordinator import Coordinator, RecoveryEvent
 from repro.core.cluster import ClusterStarEngine
+from repro.core.engine import tree_nbytes
 from repro.core.fault import ClusterConfig, FaultInjector, RecoveryCase
 from repro.db import wal as walmod
 from repro.obs import trace as obs
@@ -168,8 +171,10 @@ class ClusterRuntime:
         # mid-stream kill aborts the phase at the killed slab: a PREFIX of
         # the op stream is already applied on the replicas.
         abort_check = ((lambda s: s in slab_kills) if slab_kills else None)
-        doomed = self.eng.run_epoch(batch, ingest=ingest, commit=False,
-                                    abort_check=abort_check)
+        with obs.span("recovery.doomed", cat="recovery", epoch=self.epoch,
+                      failed=str(sorted(kills))):
+            doomed = self.eng.run_epoch(batch, ingest=ingest, commit=False,
+                                        abort_check=abort_check)
         if slab_kills and "aborted_at_slab" not in doomed:
             # a slab index past the executed range would silently test the
             # plain fence-miss path instead of the mid-stream one — discard
@@ -237,8 +242,9 @@ class ClusterRuntime:
             # donor copy from the surviving full replica (§4.5.3 case 1/3):
             # every killed node re-copies its block on rejoin, lost or not
             with obs.span("recovery.restore", cat="recovery",
-                          source="full_replica", nodes=str(sorted(kills))):
-                eng.restore_nodes_from_full(sorted(kills))
+                          source="full_replica",
+                          nodes=str(sorted(kills))) as sp:
+                sp.set(bytes=eng.restore_nodes_from_full(sorted(kills)))
         elif plan.case is RecoveryCase.FALLBACK_DIST_CC:
             # no full replica left; the partial set is complete — dead
             # blocks restore from their PHYSICAL surviving secondary
@@ -250,22 +256,24 @@ class ClusterRuntime:
             if restorable:
                 with obs.span("recovery.restore", cat="recovery",
                               source="secondary_copy",
-                              nodes=str(restorable)):
-                    eng.restore_blocks_from_secondary(restorable)
+                              nodes=str(restorable)) as sp:
+                    sp.set(bytes=eng.restore_blocks_from_secondary(
+                        restorable))
                 from_secondary = tuple(restorable)
             with obs.span("recovery.restore", cat="recovery",
-                          source="rebuild_full_from_partials"):
-                eng.rebuild_full_from_partials()
+                          source="rebuild_full_from_partials") as sp:
+                sp.set(bytes=eng.rebuild_full_from_partials())
         else:                                   # UNAVAILABLE: disk or halt
             if self.durability is None:
                 raise RuntimeError(
                     "cluster UNAVAILABLE (no full replica, incomplete "
                     "partial set) and no durability attached: halt")
             with obs.span("recovery.restore", cat="recovery",
-                          source="disk_wal"):
+                          source="disk_wal") as sp:
                 val, tid, idx, e_c = walmod.recover_full(
                     self.durability.dir)
                 eng.load_committed(val, tid, indexes=idx)
+                sp.set(bytes=tree_nbytes((val, tid, idx)))
             reloaded = True
         return RecoveryEvent(
             epoch=epoch, failed=tuple(sorted(kills)), case=plan.case,

@@ -298,6 +298,10 @@ class ClusterStarEngine:
         epoch mid-stream (a node died during the phase) with slabs
         0..s-1 already shipped: remaining slabs never execute or ship."""
         with obs.span("engine.epoch", "epoch", epoch=self.epoch) as sp:
+            if not commit:
+                # a failed node misses this epoch's fence: it re-executes
+                # under the same number, so readers tell the two apart
+                sp.set(doomed=True)
             m = self._run_epoch(batch, ingest, commit, abort_check)
             if "aborted_at_slab" not in m:
                 sp.set(committed=m["committed_single"]
@@ -672,7 +676,8 @@ class ClusterStarEngine:
         segments) from a surviving source in the committed snapshot, make
         that the committed version everywhere, and resync the rejoining
         secondary homes.  (Recovery path: the copy goes through the host —
-        source and destination live on different devices.)"""
+        source and destination live on different devices.)  Returns the
+        bytes copied into the blocks."""
         snap = dict(self._snap)
         pv = np.asarray(snap["part_val"]).copy()
         pt = np.asarray(snap["part_tid"]).copy()
@@ -681,14 +686,17 @@ class ClusterStarEngine:
         pidx = jax.tree.map(lambda a: np.asarray(a).copy(),
                             snap["part_idx"])
         sidx = jax.tree.map(np.asarray, snap[src_idx_key])
+        copied = 0
         for n in nodes:
             sl = self.node_slice(n)
             ssl = src_slice_fn(n)
             pv[sl] = sv[ssl]
             pt[sl] = st[ssl]
+            copied += pv[sl].nbytes + pt[sl].nbytes
             for pi, si in zip(pidx, sidx):
                 for k in ("key", "prow", "tid"):
                     pi[k][sl] = si[k][ssl]
+                    copied += pi[k][sl].nbytes
         snap["part_val"] = jax.device_put(jnp.asarray(pv), self._shard)
         snap["part_tid"] = jax.device_put(jnp.asarray(pt), self._shard)
         snap["part_idx"] = jax.device_put(
@@ -696,29 +704,32 @@ class ClusterStarEngine:
         self._snap = snap
         self._resync_secondary()
         self._load_state(self._snap)
+        return copied
 
     def restore_nodes_from_full(self, nodes):
         """§4.5.3 case-1/3 donor copy: rebuild the nodes' partition blocks
         from the (surviving) full replica's committed snapshot, then make
-        that the nodes' own committed version."""
-        self._restore_blocks(nodes, "full_val", "full_tid", "full_idx",
-                             self.node_slice)
+        that the nodes' own committed version.  Returns the bytes copied."""
+        return self._restore_blocks(nodes, "full_val", "full_tid",
+                                    "full_idx", self.node_slice)
 
     def restore_blocks_from_secondary(self, nodes):
         """The actual surviving-copy restore (replaces the old
         committed-snapshot stand-in): a dead node's primary block is
         rebuilt from the PHYSICAL secondary copy its neighbor holds —
         the copy itself, not an un-scribbled convenience alias.  Block
-        n's secondary copy sits in its sec home's slice rows."""
+        n's secondary copy sits in its sec home's slice rows.  Returns the
+        bytes copied."""
         assert self.secondary, "no physical secondary replicas configured"
-        self._restore_blocks(nodes, "sec_val", "sec_tid", "sec_idx",
-                             lambda n: self.node_slice(self.sec_home(n)))
+        return self._restore_blocks(
+            nodes, "sec_val", "sec_tid", "sec_idx",
+            lambda n: self.node_slice(self.sec_home(n)))
 
     def rebuild_full_from_partials(self):
         """§4.5.3 case 2: every partition still has a live partial copy
         but no full replica survives — re-replicate a full copy by
         gathering the committed partial set (the bootstrap all-gather,
-        again), index segments included."""
+        again), index segments included.  Returns the bytes copied."""
         snap = dict(self._snap)
         fv = jax.device_put(jnp.asarray(snap["part_val"]), self._master_dev)
         ft = jax.device_put(jnp.asarray(snap["part_tid"]), self._master_dev)
@@ -728,6 +739,7 @@ class ClusterStarEngine:
         self._snap = snap
         self._resync_secondary()
         self._load_state(self._snap)
+        return tree_nbytes((fv, ft, snap["full_idx"]))
 
     def _resync_secondary(self):
         """§4.5.3 catch-up for rejoining secondary homes: rebuild the
@@ -833,7 +845,11 @@ class MeshPrograms:
     """The cluster engine's jitted programs over one ``part`` mesh.  They
     are built from the mesh and the table shapes alone, with no device
     state, so the same programs can be lowered for a described topology
-    (``tests/test_tpu_compile.py``)."""
+    (``tests/test_tpu_compile.py``).
+
+    Each program is a named function, so that it runs, and shows in a
+    device trace, as ``jit_cluster_<what>``, a name no other program has.
+    """
 
     def __init__(self, mesh, n_partitions: int, rows_per_partition: int,
                  n_cols: int, index_specs, max_rounds: int, kernel: str):
@@ -845,14 +861,14 @@ class MeshPrograms:
         # home over the interconnect (ICI on a TPU slice)
         home_perm = [(m, (m + 1) % N) for m in range(N)]
 
-        def ship_home(tree):
+        def cluster_ship_home(tree):
             return jax.tree.map(
                 lambda a: jax.lax.ppermute(a, "part", home_perm), tree)
 
         self.ship_home = jax.jit(shard_map(
-            ship_home, mesh, in_specs=(pspec,), out_specs=pspec))
+            cluster_ship_home, mesh, in_specs=(pspec,), out_specs=pspec))
 
-        def part_phase(val, tid, index, seq, ptxn, epoch):
+        def cluster_partitioned(val, tid, index, seq, ptxn, epoch):
             # NO collectives inside: single-partition txns need none (§4.1).
             # part_ids map this block's local segment rows to their global
             # partition ids so index maintenance lands on the right keys.
@@ -879,27 +895,30 @@ class MeshPrograms:
             log_keys += ["iwrite", "cskip"]
         log_spec = {k: P("part") for k in log_keys}
         self.part = jax.jit(shard_map(
-            part_phase, mesh,
+            cluster_partitioned, mesh,
             in_specs=(pspec, pspec, idx_spec, pspec, txn_spec, P()),
             out_specs=(pspec, pspec, idx_spec, pspec, log_spec, pspec,
                        pspec)))
 
-        def fence(commit_counts):
+        def cluster_fence(commit_counts):
             # §4.3: nodes exchange commit statistics; the psum is the barrier
             return jax.lax.psum(commit_counts, "part")
 
         self.fence_barrier = jax.jit(shard_map(
-            fence, mesh, in_specs=(P("part"),), out_specs=P()))
+            cluster_fence, mesh, in_specs=(P("part"),), out_specs=P()))
 
         # single-master phase runs on the master's device only (its full
         # copy lives there) — no 2PC, no cross-device coordination during
         # execution; the write stream ships back through the scatters
-        self.sm = jax.jit(
-            lambda v, t, idx, txns, epoch: run_single_master(
-                v, t, txns, epoch, max_rounds=max_rounds,
-                index=idx if has_index else None, kernel=kernel))
+        def cluster_single_master(v, t, idx, txns, epoch):
+            return run_single_master(v, t, txns, epoch,
+                                     max_rounds=max_rounds,
+                                     index=idx if has_index else None,
+                                     kernel=kernel)
 
-        def scatter_back(part_val, part_tid, rows, vals, tids):
+        self.sm = jax.jit(cluster_single_master)
+
+        def cluster_scatter_back(part_val, part_tid, rows, vals, tids):
             """Apply the master's write stream to the partition owners:
             each device filters the global stream to its own row range."""
             pid = jax.lax.axis_index("part")
@@ -912,11 +931,11 @@ class MeshPrograms:
             return v.reshape(ppn, R, C), t.reshape(ppn, R)
 
         self.scatter = jax.jit(shard_map(
-            scatter_back, mesh,
+            cluster_scatter_back, mesh,
             in_specs=(pspec, pspec, P(), P(), P()),
             out_specs=(pspec, pspec)))
 
-        def scatter_back_sec(sec_val, sec_tid, rows, vals, tids):
+        def cluster_scatter_back_sec(sec_val, sec_tid, rows, vals, tids):
             """Same stream, delivered to each block's SECONDARY home: node
             m's sec block holds node (m-1)'s partitions (home-major)."""
             pid = jax.lax.axis_index("part")
@@ -929,18 +948,21 @@ class MeshPrograms:
             return v.reshape(ppn, R, C), t.reshape(ppn, R)
 
         self.scatter_sec = jax.jit(shard_map(
-            scatter_back_sec, mesh,
+            cluster_scatter_back_sec, mesh,
             in_specs=(pspec, pspec, P(), P(), P()),
             out_specs=(pspec, pspec)))
 
         # ordered op-stream replay onto the full replica — jitted once; an
         # eager form here would retrace EVERY slab (host-bound).  One slab
         # = one jitted replay of its slot range (records + index ops).
-        self.replay_full = jax.jit(
-            lambda v, t, log, idx: repl.replay_partitioned(
-                v, t, log, idx if has_index else None, kernel=kernel))
+        def cluster_replay_full(v, t, log, idx):
+            return repl.replay_partitioned(v, t, log,
+                                           idx if has_index else None,
+                                           kernel=kernel)
 
-        def replay_sec(v, t, log, idx):
+        self.replay_full = jax.jit(cluster_replay_full)
+
+        def cluster_replay_sec(v, t, log, idx):
             # the permute IS the ship: each block's ordered stream moves
             # to its secondary home, which replays it on the copy it hosts
             # (node m holds node m-1's partitions)
@@ -949,24 +971,25 @@ class MeshPrograms:
                                + jnp.arange(ppn, dtype=jnp.int32),
                                n_partitions)
             v, t, idx_out = repl.replay_partitioned(
-                v, t, ship_home(log),
+                v, t, cluster_ship_home(log),
                 idx if has_index else None, part_ids=part_ids,
                 kernel=kernel)
             return v, t, (idx_out if has_index else idx)
 
         self.replay_sec = jax.jit(shard_map(
-            replay_sec, mesh, in_specs=(pspec, pspec, pspec, idx_spec),
+            cluster_replay_sec, mesh,
+            in_specs=(pspec, pspec, pspec, idx_spec),
             out_specs=(pspec, pspec, idx_spec)))
 
         if has_index:
-            def sm_idx_replay(idx, kinds, delta, iwrite, tids):
+            def cluster_index_replay(idx, kinds, delta, iwrite, tids):
                 pid = jax.lax.axis_index("part")
                 part_ids = pid * ppn + jnp.arange(ppn, dtype=jnp.int32)
                 return repl.replay_index_rounds(idx, kinds, delta, iwrite,
                                                 tids, part_ids=part_ids,
                                                 kernel=kernel)
 
-            def sm_idx_replay_sec(idx, kinds, delta, iwrite, tids):
+            def cluster_index_replay_sec(idx, kinds, delta, iwrite, tids):
                 pid = jax.lax.axis_index("part")
                 part_ids = jnp.mod(
                     pid * ppn + jnp.arange(ppn, dtype=jnp.int32) - ppn,
@@ -977,7 +1000,8 @@ class MeshPrograms:
 
             bspecs = (idx_spec, P(), P(), P(), P())
             self.sm_idx_replay = jax.jit(shard_map(
-                sm_idx_replay, mesh, in_specs=bspecs, out_specs=idx_spec))
+                cluster_index_replay, mesh, in_specs=bspecs,
+                out_specs=idx_spec))
             self.sm_idx_replay_sec = jax.jit(shard_map(
-                sm_idx_replay_sec, mesh, in_specs=bspecs,
+                cluster_index_replay_sec, mesh, in_specs=bspecs,
                 out_specs=idx_spec))

@@ -64,6 +64,43 @@ def test_runtime_parity_and_case1_failover():
     assert "OK case1" in out
 
 
+def test_named_cluster_programs_stay_bit_identical_to_star_engine():
+    """With every mesh program a named function, four nodes (ppn=2) end
+    each epoch in StarEngine's state bit for bit: every copy's values and
+    TIDs (master blocks, full replica, secondaries) and every commit
+    decision."""
+    out = _run("""
+        import jax, numpy as np
+        from repro.cluster import ClusterRuntime
+        from repro.core.engine import StarEngine
+        from repro.db import ycsb
+        cfg = ycsb.YCSBConfig(n_partitions=8, records_per_partition=128,
+                              cross_ratio=0.2)
+        mesh = jax.make_mesh((4,), ("part",), devices=jax.devices()[:4])
+        rt = ClusterRuntime(mesh, 8, 128)
+        eng = StarEngine(8, 128)
+        ppn = rt.eng.ppn
+        for ep in range(4):
+            batch = ycsb.make_batch(cfg, 160, seed=10 + ep)
+            mc = rt.run_epoch(batch)
+            ms = eng.run_epoch(batch)
+            for k in ("p_committed", "c_committed"):
+                assert np.array_equal(np.asarray(mc[k]),
+                                      np.asarray(ms[k])), (ep, k)
+            want_v = np.asarray(eng.master["val"])
+            want_t = np.asarray(eng.master["tid"])
+            for v, t in ((rt.eng.part_val, rt.eng.part_tid),
+                         (rt.eng.full_val, rt.eng.full_tid),
+                         (np.roll(np.asarray(rt.eng.sec_val), -ppn, 0),
+                          np.roll(np.asarray(rt.eng.sec_tid), -ppn, 0))):
+                assert np.array_equal(np.asarray(v), want_v), ep
+                assert np.array_equal(np.asarray(t), want_t), ep
+        assert int(np.asarray(mc["c_committed"]).sum()) > 0
+        print("OK parity")
+    """)
+    assert "OK parity" in out
+
+
 def test_runtime_unavailable_reloads_from_disk():
     """Killing the full-replica node plus both homes of a partition block
     leaves neither a full replica nor a complete partial set: UNAVAILABLE.

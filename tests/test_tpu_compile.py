@@ -6,7 +6,9 @@ cross-partition lanes) — each program must pass the TPU compiler and fit
 one chip's 16 GB.  The one-chip programs are StarEngine's partitioned
 phase, single-master phase and op-stream replay; the four-chip programs are
 the cluster engine's ``shard_map`` partitioned phase and its secondary
-replay, whose op-stream ship is a collective permute over ``part``.
+replay, whose op-stream ship is a collective permute over ``part``.  At
+the ``ycsb-64p-4n`` deployment's shapes every cluster program compiles
+under a name of its own, and the master chip's full-replica programs fit.
 
 The topology is described inside a fixture (never at import), and the
 persistent compilation cache is off around the compiles: entries written
@@ -33,6 +35,20 @@ N_WAREHOUSES, T_SLOTS, LANES, N_SLABS, ROUNDS = 16, 128, 128, 4, 16
 # the ycsb-16p deployment: 16 partitions of 200,000 rows of 25 int32 words,
 # 64 queue slots per partition, 10 ops per transaction
 YCSB_P, YCSB_R, YCSB_C, YCSB_T, YCSB_M = 16, 200_000, 25, 64, 10
+# the ycsb-64p-4n deployment: the same rows on four nodes of 16 partitions,
+# 512 cross-partition lanes on the master
+YCSB4_P, YCSB4_LANES = 64, 512
+# every cluster program and the name it runs under in a device trace
+CLUSTER_NAMES = {"ship_home": "jit_cluster_ship_home",
+                 "part": "jit_cluster_partitioned",
+                 "fence_barrier": "jit_cluster_fence",
+                 "sm": "jit_cluster_single_master",
+                 "scatter": "jit_cluster_scatter_back",
+                 "scatter_sec": "jit_cluster_scatter_back_sec",
+                 "replay_full": "jit_cluster_replay_full",
+                 "replay_sec": "jit_cluster_replay_sec",
+                 "sm_idx_replay": "jit_cluster_index_replay",
+                 "sm_idx_replay_sec": "jit_cluster_index_replay_sec"}
 
 
 @pytest.fixture(scope="module")
@@ -173,3 +189,90 @@ def test_partitioned_commit_in_place_for_v5e_ycsb16(topo):
     table_bytes = P * R * C * 4
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.5 * table_bytes, (temp, table_bytes)
+
+
+def _module_name(hlo: str) -> str:
+    return hlo.split("HloModule ", 1)[1].split(",", 1)[0].strip()
+
+
+def _cluster_args(prog, mesh, P, R, C, T, M, B, index):
+    """Abstract arguments of every program of ``prog``: one op-stream slab
+    of ``T`` queue slots, ``B`` cross-partition lanes, ``index`` the
+    partition-sharded index segments (a list, empty without indexes)."""
+    shard = NamedSharding(mesh, PartitionSpec("part"))
+    bcast = NamedSharding(mesh, PartitionSpec())
+    master = SingleDeviceSharding(mesh.devices.flat[0])
+    N = mesh.devices.size
+
+    def txns(lead, sharding):
+        return {"valid": _sds(lead, jnp.bool_, sharding),
+                "row": _sds(lead + (M,), jnp.int32, sharding),
+                "kind": _sds(lead + (M,), jnp.int32, sharding),
+                "delta": _sds(lead + (M, C), jnp.int32, sharding),
+                "user_abort": _sds(lead, jnp.bool_, sharding)}
+    val, tid = _sds((P, R, C), jnp.int32, shard), \
+        _sds((P, R), jnp.uint32, shard)
+    epoch = _sds((), jnp.uint32, bcast)
+    part = (val, tid, index, _sds((P,), jnp.uint32, shard),
+            txns((P, T), shard), epoch)
+    log = _place(jax.eval_shape(prog.part, *part)[4], shard)
+    fval = _sds((P * R, C), jnp.int32, master)
+    ftid = _sds((P * R,), jnp.uint32, master)
+    findex = _place(index, master)
+    cross = txns((B,), master)
+    m_epoch = _sds((), jnp.uint32, master)
+    sm = (fval, ftid, findex, cross, m_epoch)
+    slog = jax.eval_shape(prog.sm, *sm)[2]["log"]
+    n = int(np.prod(slog["write"].shape))
+    stream = (_sds((n,), jnp.int32, bcast), _sds((n, C), jnp.int32, bcast),
+              _sds((n,), jnp.uint32, bcast))
+    args = {"ship_home": (val,), "part": part,
+            "fence_barrier": (_sds((N,), jnp.int32, shard),),
+            "sm": sm,
+            "scatter": (val, tid) + stream,
+            "scatter_sec": (val, tid) + stream,
+            "replay_full": (_sds((P, R, C), jnp.int32, master),
+                            _sds((P, R), jnp.uint32, master),
+                            _place(log, master), findex),
+            "replay_sec": (val, tid, log, index)}
+    if index:
+        args["sm_idx_replay"] = args["sm_idx_replay_sec"] = (
+            index, _sds((B, M), jnp.int32, bcast),
+            _sds((B, M, C), jnp.int32, bcast),
+            _place(slog["iwrite"], bcast), _place(slog["tid"], bcast))
+    return args
+
+
+def test_cluster_programs_run_under_fixed_names_for_v5e_2x2(topo, cfg):
+    """Every cluster program, index replays included (TPC-C's indexes),
+    lowers for a described v5e:2x2 as ``jit_cluster_<what>``, one name per
+    program, so a device trace tells each apart."""
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("part",))
+    P, R, C = cfg.n_partitions, cfg.rows_per_partition, tpcc.C
+    prog = MeshPrograms(mesh, P, R, C, tpcc.index_specs(cfg), ROUNDS, "jnp")
+    index = _place(_shapes(cfg)[3], NamedSharding(mesh,
+                                                  PartitionSpec("part")))
+    args = _cluster_args(prog, mesh, P, R, C, T_SLOTS // N_SLABS, tpcc.M,
+                         LANES, index)
+    programs = {k for k, v in vars(prog).items() if hasattr(v, "lower")}
+    assert programs == set(CLUSTER_NAMES) == set(args)
+    names = {k: getattr(prog, k).lower(*args[k]).as_text().split(
+        "module @", 1)[1].split(" ", 1)[0] for k in programs}
+    assert names == CLUSTER_NAMES
+
+
+def test_cluster_programs_compile_for_v5e_2x2_at_ycsb64_4n(topo):
+    """At ycsb-64p-4n's shapes (64 partitions of 200,000 rows of 25 words,
+    16-slot op-stream slabs, 512 lanes) every cluster program compiles under
+    its own name, and the master chip's full-replica replay and
+    single-master phase each fit one chip's 16 GB."""
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("part",))
+    P, R, C, M = YCSB4_P, YCSB_R, YCSB_C, YCSB_M
+    prog = MeshPrograms(mesh, P, R, C, [], ROUNDS, "jnp")
+    args = _cluster_args(prog, mesh, P, R, C, YCSB_T // N_SLABS, M,
+                         YCSB4_LANES, [])
+    for k, a in args.items():
+        compiled = getattr(prog, k).lower(*a).compile()
+        txt = _check(compiled) if k in ("replay_full", "sm") \
+            else compiled.as_text()
+        assert _module_name(txt) == CLUSTER_NAMES[k], k
